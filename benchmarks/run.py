@@ -5,8 +5,9 @@ Prints ``name,us_per_call,derived`` CSV rows:
 * ``fig7_*``   — VGG-11 duplication/reuse tile counts (Fig. 7)
 * ``fig11_*``  — normalized-CE comparison factors (Fig. 11)
 * ``fig12_*``  — crossbar utilization vs array size (Fig. 12)
-* ``kernel_*`` — Pallas CIM matmul vs jnp reference wall time (CPU
-  interpret mode: correctness-path timing, not TPU perf)
+* ``kernel_*`` — Pallas CIM matmul vs jnp reference wall time (the
+  kernel is interpreted off a TPU: there the row is correctness-path
+  timing, not TPU perf)
 * ``stream_*`` — measured pipelined stream computing per model: the
   steady-state initiation interval from the simulated stage timeline
   vs ``plan_network``'s analytic bound, per-frame OFMs bitwise-checked
@@ -151,7 +152,7 @@ def bench_kernels():
     spec = CIMSpec()
 
     us_p, out_p = _t(lambda: jax.block_until_ready(
-        cim_matmul_pallas(xq, wq, spec, interpret=True)))
+        cim_matmul_pallas(xq, wq, spec)))
     us_r, out_r = _t(lambda: jax.block_until_ready(
         cim_matmul_ref(xq, wq, spec)))
     exact = bool(np.array_equal(np.asarray(out_p), np.asarray(out_r)))
@@ -290,12 +291,12 @@ def bench_network_sim_resnet():
     sim = NetworkSimulator(cnn, params, backend="trace")
     us, res = _t(lambda: sim.run(x), reps=2)
 
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.models.cnn import cnn_forward
 
-    with enable_x64():
+    with jax.enable_x64(True):
         p64 = {k: jnp.asarray(v, jnp.float64) for k, v in params.items()}
         ref = np.asarray(cnn_forward(p64, jnp.asarray(x, jnp.float64), cnn))
     match = bool(np.allclose(res.logits, ref, rtol=1e-9))
@@ -1316,6 +1317,8 @@ def main(argv=None) -> None:
                          "timeline) — open in https://ui.perfetto.dev")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.check_regress:
         raise SystemExit(check_regress(args.check_regress))
     if args.stream_smoke:
@@ -1344,13 +1347,15 @@ def main(argv=None) -> None:
         benches.append(bench_dse)
     if args.chiplet:
         benches.append(bench_chiplet)
+    failed = []
     for fn in benches:
         try:
             for name, us, derived in fn():
                 print(f"{name},{us:.1f},{derived}")
                 rows.append({"name": name, "us_per_call": round(us, 2),
                              "derived": derived})
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 - reported, then exit 1
+            failed.append(fn.__name__)
             print(f"{fn.__name__},0,ERROR {type(e).__name__}: {e}")
             rows.append({"name": fn.__name__, "us_per_call": 0.0,
                          "derived": f"ERROR {type(e).__name__}: {e}"})
@@ -1378,6 +1383,9 @@ def main(argv=None) -> None:
         prof.uninstall()
         write_chrome_trace(args.trace_out, prof.events)
         print(f"# wrote {args.trace_out} ({len(prof.events)} trace events)")
+    if failed:
+        raise SystemExit(f"# {len(failed)} bench(es) failed: "
+                         f"{', '.join(failed)}")
 
 
 if __name__ == "__main__":

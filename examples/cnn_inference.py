@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.cnn import CNN_BENCHMARKS
 from repro.core.cim import CIMSpec
 from repro.core.energy import analyze_plan
@@ -48,6 +49,7 @@ from repro.models.cnn import cnn_forward, init_cnn
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--placement", default=None,
                     choices=("snake", "boustrophedon", "hilbert", "greedy"),

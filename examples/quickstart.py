@@ -12,6 +12,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.configs.base import ParallelConfig, TrainConfig
 from repro.data.pipeline import DataSpec, synthetic_batch
@@ -21,6 +22,7 @@ from repro.runtime.train_loop import build_train_program
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("qwen2-0.5b").reduced()
     mesh = make_host_mesh()
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))}")

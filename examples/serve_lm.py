@@ -12,6 +12,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.configs.base import ParallelConfig, TrainConfig
 from repro.launch.mesh import make_host_mesh
@@ -24,6 +25,7 @@ from repro.runtime.train_loop import build_train_program
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b")
     ap.add_argument("--batch", type=int, default=4)

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.configs.base import AttentionConfig, ParallelConfig, TrainConfig
 from repro.data.pipeline import DataSpec, Prefetcher, synthetic_batch
@@ -45,6 +46,7 @@ def model_100m():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

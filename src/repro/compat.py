@@ -1,79 +1,36 @@
-"""Version compatibility shims for the jax API surface we depend on.
+"""Thin wrappers over the jax API surface the runtime and tests share.
 
-``shard_map`` moved twice across jax releases:
-
-* jax <= 0.4.x: ``jax.experimental.shard_map.shard_map`` with the
-  ``check_rep`` keyword;
-* newer jax: top-level ``jax.shard_map`` with ``check_rep`` renamed to
-  ``check_vma``.
-
-Everything in ``runtime/``, ``launch/`` and the tests goes through
-:func:`shard_map` below so the repo runs on either line.
+Each one states what the installed jax does, so call sites stay short:
+``jax.shard_map`` with replication checking off by default, meshes with
+explicit ``Auto`` axis types, and the sharding-invariant threefry RNG.
 """
 from __future__ import annotations
 
-from typing import Any, Callable
-
 import jax
+from jax import lax
 
 
 def axis_size(axis: str) -> int:
-    """``lax.axis_size`` (new jax) with the classic ``psum(1, axis)``
-    fallback, which constant-folds to the mesh axis size."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+    """Size of the named mesh axis inside a ``shard_map`` body."""
+    return lax.axis_size(axis)
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with explicit Auto axis types where the jax
-    version supports them (the kwarg and ``jax.sharding.AxisType`` only
-    exist on newer lines)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of type ``Auto``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def partitionable_rng():
     """Context manager forcing the sharding-invariant threefry
-    implementation (the default on newer jax lines).  Sharded param init
-    must produce the same values regardless of output shardings — on
-    jax 0.4.x the default (False) makes ZeRO-3 init diverge from the
-    replicated baseline."""
-    import contextlib
-
-    cm = getattr(jax, "threefry_partitionable", None)
-    if cm is None:
-        try:
-            from jax._src.config import threefry_partitionable as cm
-        except ImportError:  # very old/new layout: fall back to a no-op
-            return contextlib.nullcontext()
-    if jax.config.jax_threefry_partitionable:
-        return contextlib.nullcontext()  # already the (new) default
-    return cm(True)
-
-
-def resolve_shard_map() -> Callable[..., Any]:
-    """Return the raw shard_map callable for this jax version."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp
+    implementation, so sharded param init produces the same values
+    regardless of output shardings."""
+    return jax.threefry_partitionable(True)
 
 
 def shard_map(fn, mesh, in_specs, out_specs, *, check: bool = False):
-    """Uniform wrapper: replication checking off by default (our manual
-    collectives intentionally produce device-varying intermediates)."""
-    raw = resolve_shard_map()
-    try:
-        return raw(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check)
-    except TypeError:  # older keyword spelling
-        return raw(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check)
+    """``jax.shard_map`` with replication checking off by default (our
+    manual collectives intentionally produce device-varying
+    intermediates)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -132,8 +132,29 @@ def adc_quantize(d: jax.Array, spec: CIMSpec) -> jax.Array:
     ``d`` is int32 (exact dot over <=n_c rows).  Output is int32 ADC codes
     in [-q_max-1, q_max].
     """
-    codes = jnp.round(d.astype(jnp.float32) * spec.adc_inv_step)
-    return jnp.clip(codes, -spec.q_max - 1, spec.q_max).astype(jnp.int32)
+    return adc_convert_jnp(d, spec.adc_inv_step, -spec.q_max - 1,
+                           spec.q_max)
+
+
+def adc_convert_jnp(d: jax.Array, inv_step32, code_lo, code_hi,
+                    offset=None) -> jax.Array:
+    """Traceable twin of :func:`adc_convert` (the jitted trace path and
+    the Pallas kernel body): the same f32 multiply(+add), round
+    half-to-even and saturate, as int32 codes — so a zero code is +0.0
+    once cast back to float.
+
+    Compilers fuse a multiply that feeds an add into one fused
+    multiply-add (XLA's CPU backend does), which rounds once where the
+    numpy conversion rounds twice and moves a code whenever the exact
+    value lies within an f32 ulp of a half-code boundary.  So with an
+    offset the product first passes through a max with the lowest finite
+    f32: the identity on these finite products, and not an operand any
+    compiler can fuse into the add.
+    """
+    acc = d.astype(jnp.float32) * inv_step32
+    if offset is not None:
+        acc = jnp.maximum(acc, jnp.finfo(jnp.float32).min) + offset
+    return jnp.clip(jnp.round(acc), code_lo, code_hi).astype(jnp.int32)
 
 
 def adc_dequantize(codes: jax.Array, spec: CIMSpec) -> jax.Array:
@@ -155,14 +176,18 @@ def adc_convert(d: np.ndarray, inv_step32, code_lo: float, code_hi: float,
     :class:`~repro.core.variation.VariationModel`); ``offset`` (same
     broadcast rules, in code LSBs, added before rounding) models the
     per-subarray SAR comparator offset.  ``offset=None`` leaves the
-    arithmetic byte-identical to the nominal two-op conversion.
+    arithmetic byte-identical to the nominal two-op conversion.  A zero
+    code is +0.0 (``round`` of a small negative value is -0.0), as the
+    int32 codes of the jnp and kernel paths are.
     """
     d = np.asarray(d)
     acc = (d.astype(np.int32).astype(np.float32)
            * np.asarray(inv_step32, np.float32))
     if offset is not None:
         acc = acc + np.asarray(offset, np.float32)
-    return np.clip(np.round(acc), code_lo, code_hi).astype(np.float64)
+    codes = np.clip(np.round(acc), code_lo, code_hi).astype(np.float64)
+    codes += 0.0
+    return codes
 
 
 def calibrate_gain(x, w, spec: CIMSpec, percentile: float = 100.0) -> float:

@@ -22,8 +22,9 @@ wavefront and the FC grid all call the *same* engine object:
   under quantization *by construction*;
 * :class:`PallasEngine` — the same quantization state, but the integer
   dot + ADC runs through the Pallas kernel
-  (``kernels/cim_matmul.py::cim_matmul_pallas``, interpret mode
-  off-TPU).  Each tile call is one kernel subarray step, so its ADC
+  (``kernels/cim_matmul.py::cim_matmul_pallas``, compiled on a TPU and
+  interpreted elsewhere) on every executor path, the jitted trace path
+  included.  Each tile call is one kernel subarray step, so its ADC
   codes are bitwise-identical to :class:`CIMEngine`'s.
 
 ADC-code equality across the jnp / numpy / Pallas flavors holds because
@@ -575,6 +576,34 @@ class CIMEngine(PEEngine):
                                 h.adc_off[:, None, None])
         return codes.sum(axis=0)
 
+    def tiles_mac_fn(self, h):
+        """The jit flavor of :meth:`tiles_mac`, for ``TraceExecutor``'s
+        jitted path: ``(fn, operands)`` where ``fn(x, *operands)`` is
+        traceable, ``x`` the (T, R, max kc) int8 patch stack, and the
+        result the (R, M) code sums.  One batched ``lax.dot_general``
+        with int32 accumulation, the shared f32 ADC conversion, and an
+        exact int32 code sum (a zero sum is +0.0 on the host)."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        from repro.core.cim import adc_convert_jnp
+
+        if h.adc_inv is None:
+            inv, off = np.float32(h.inv_step32), None
+        else:
+            # per-subarray ADC variation rides the same fused dot: the
+            # (T,) arrays broadcast over the (T, R, M) dot tensor
+            inv = np.asarray(h.adc_inv, np.float32).reshape(-1, 1, 1)
+            off = np.asarray(h.adc_off, np.float32).reshape(-1, 1, 1)
+        clo, chi = np.float32(h.code_lo), np.float32(h.code_hi)
+
+        def fn(x, w8):
+            d = lax.dot_general(x, w8, (((2,), (1,)), ((0,), (0,))),
+                                preferred_element_type=jnp.int32)
+            return adc_convert_jnp(d, inv, clo, chi, off).sum(axis=0)
+
+        return fn, (h.w8_stack,)
+
     def finalize_conv(self, h, acc):
         return acc * h.deq
 
@@ -618,19 +647,11 @@ class PallasEngine(CIMEngine):
     one ``cim_matmul_pallas`` call whose single K-step *is* the tile's
     subarray (the kernel zero-pads K to ``n_c`` — padding rows contribute
     nothing to the exact dot), emitting raw ADC codes.  Bitwise-identical
-    codes to :class:`CIMEngine` by construction; off-TPU the kernel runs
-    in interpret mode (the validation target), on hardware pass
-    ``interpret=False``."""
+    codes to :class:`CIMEngine` by construction.  The kernel is compiled
+    on a TPU and interpreted elsewhere
+    (``kernels/cim_matmul.py::interpret_mode``)."""
 
     name = "pallas"
-
-    def __init__(self, spec: CIMSpec = DEFAULT_SPEC,
-                 use_calibrated_gain: bool = True, interpret: bool = True,
-                 clip_percentile: Optional[float] = None,
-                 variation: Optional[VariationModel] = None):
-        super().__init__(spec, use_calibrated_gain,
-                         clip_percentile=clip_percentile, variation=variation)
-        self.interpret = interpret
 
     def _codes(self, xq8: np.ndarray, wq8: np.ndarray, spec: CIMSpec,
                adc_var: Optional[np.ndarray] = None) -> np.ndarray:
@@ -639,8 +660,7 @@ class PallasEngine(CIMEngine):
         from repro.kernels.cim_matmul import cim_matmul_pallas
 
         codes = cim_matmul_pallas(
-            jnp.asarray(xq8), jnp.asarray(wq8), spec,
-            interpret=self.interpret, emit_codes=True,
+            jnp.asarray(xq8), jnp.asarray(wq8), spec, emit_codes=True,
             adc_var=None if adc_var is None else jnp.asarray(adc_var))
         return np.asarray(codes, np.float64)
 
@@ -655,6 +675,21 @@ class PallasEngine(CIMEngine):
             av = np.stack([h.adc_inv[t:t + 1], h.adc_off[t:t + 1]], axis=1)
         return self._codes(xq, wq, h.spec, av)
 
+    def _chain_operands(self, h):
+        """A tile chain's kernel operands: the (T * n_c, M) int8 weights,
+        each tile's ``kc``-row slab zero-padded to its own ``n_c``-row K
+        block, and the (T, 2) per-subarray ADC table (None = nominal) —
+        kernel K step i is chain tile i."""
+        if h.w8_sub is None:
+            t, kcm, m = h.w8_stack.shape
+            sub = np.zeros((t, h.spec.n_c, m), np.int8)
+            sub[:, :kcm] = h.w8_stack
+            h.w8_sub = sub.reshape(t * h.spec.n_c, m)
+        av = None
+        if h.adc_inv is not None:
+            av = np.stack([h.adc_inv, h.adc_off], axis=1)
+        return h.w8_sub, av
+
     def tiles_mac(self, h, patches):
         """Batch-of-tiles MAC through ONE multi-tile ``emit_codes``
         kernel invocation: each tile's ``kc`` activation columns land in
@@ -666,19 +701,29 @@ class PallasEngine(CIMEngine):
 
         t, r, kcm = patches.shape
         n_c = h.spec.n_c
-        if h.w8_sub is None:
-            sub = np.zeros((t, n_c, h.c_out), np.int8)
-            sub[:, :h.w8_stack.shape[1]] = h.w8_stack
-            h.w8_sub = sub.reshape(t * n_c, h.c_out)
+        w, av = self._chain_operands(h)
         x = np.zeros((r, t, n_c), np.int8)
         x[:, :, :kcm] = patches.transpose(1, 0, 2)
-        av = None
-        if h.adc_inv is not None:  # kernel K step i == chain tile i
-            av = np.stack([h.adc_inv, h.adc_off], axis=1)
-        codes = cim_chain_codes_pallas(x.reshape(r, t * n_c), h.w8_sub,
-                                       h.spec, interpret=self.interpret,
+        codes = cim_chain_codes_pallas(x.reshape(r, t * n_c), w, h.spec,
                                        adc_var=av)
         return np.asarray(codes, np.float64)
+
+    def tiles_mac_fn(self, h):
+        """The jit flavor of :meth:`tiles_mac`: the same n_c-block
+        layout, built on the traced patch stack, into the same kernel."""
+        import jax.numpy as jnp
+
+        from repro.kernels.cim_matmul import cim_chain_codes_pallas
+
+        spec = h.spec
+
+        def fn(x, w, av):
+            t, r, kcm = x.shape
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, spec.n_c - kcm)))
+            x = x.transpose(1, 0, 2).reshape(r, t * spec.n_c)
+            return cim_chain_codes_pallas(x, w, spec, adc_var=av)
+
+        return fn, self._chain_operands(h)
 
     def fc_mac(self, h, x, k0, k1, n0, n1, quantized=False):
         xq = (x if quantized else self._quant(x, h)).astype(np.int8)

@@ -64,6 +64,7 @@ interpreter's routed sends do.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
@@ -360,12 +361,14 @@ class TraceExecutor:
     # -- quantized jax fast path (bitwise, unlike the exact f32 one) ---------
 
     def _run_jax_quant(self, stream: np.ndarray) -> np.ndarray:
-        """jit flavor of the fused path: int8 gathers + one batched
-        ``lax.dot_general(..., preferred_element_type=int32)`` + the
-        shared f32 ADC conversion + the exact integer code sum.  Every
-        op is exact-integer or the shared elementwise conversion, so
-        this path is *bitwise* equal to the numpy fused/per-tile paths
-        (codes are < 2^24, exact in f32)."""
+        """jit flavor of the fused path: int8 gathers + the engine's jit
+        MAC (:meth:`CIMEngine.tiles_mac_fn` — one batched
+        ``lax.dot_general(..., preferred_element_type=int32)`` and the
+        shared f32 ADC conversion on the CIM engine, the Pallas kernel on
+        the Pallas engine) + the exact integer code sum.  Every op is
+        exact-integer or the shared elementwise conversion, so this path
+        is *bitwise* equal to the numpy fused/per-tile paths (codes are
+        < 2^24, exact in f32)."""
         s = self.sched
         qs = self.engine.quant_stream(self.handle, stream)
         if self._jax_fn is None:
@@ -377,46 +380,30 @@ class TraceExecutor:
         return self._tail_np(out)
 
     def _build_jax_qfn(self):
+        """The jitted step with the engine's operands bound first:
+        ``step(stream)``; ``step.func`` and ``step.args`` are the jitted
+        function and those operands (to lower it from shapes)."""
         import jax
         import jax.numpy as jnp
-        from jax import lax
 
-        s, plan = self.sched, self.plan
-        h = self.handle
+        plan = self.plan
         ef = plan.fires
-        kcs, kcm = h.kc, max(h.kc)
-        w8 = np.zeros((len(plan.tiles), kcm, s.c_out), np.int8)
-        w8[:, :h.w8_stack.shape[1]] = h.w8_stack
-        if h.adc_inv is None:
-            inv, off = np.float32(h.inv_step32), None
-        else:
-            # per-subarray ADC variation rides the same fused dot: the
-            # (T,) arrays broadcast over the (T, B, EF, M) code tensor
-            inv = np.asarray(h.adc_inv, np.float32).reshape(-1, 1, 1, 1)
-            off = np.asarray(h.adc_off, np.float32).reshape(-1, 1, 1, 1)
-        clo, chi = np.float32(h.code_lo), np.float32(h.code_hi)
+        kcs, kcm = self.handle.kc, max(self.handle.kc)
+        mac, operands = self.engine.tiles_mac_fn(self.handle)
 
-        def fn(stream, w8s):
+        def fn(ops, stream):
             b = stream.shape[0]
             pats = []
             for i, tt in enumerate(plan.tiles):
                 p = jnp.take(stream, tt.gather, axis=1)  # (B, pack, EF, C)
                 p = p[..., tt.c_lo:tt.c_hi].transpose(0, 2, 1, 3)
-                p = p.reshape(b, ef, kcs[i])
+                p = p.reshape(b * ef, kcs[i])
                 if kcs[i] < kcm:
-                    p = jnp.pad(p, ((0, 0), (0, 0), (0, kcm - kcs[i])))
+                    p = jnp.pad(p, ((0, 0), (0, kcm - kcs[i])))
                 pats.append(p)
-            x = jnp.stack(pats)                          # (T, B, EF, kc) i8
-            d = lax.dot_general(x, w8s, (((3,), (1,)), ((0,), (0,))),
-                                preferred_element_type=jnp.int32)
-            acc = d.astype(jnp.float32) * inv
-            if off is not None:
-                acc = acc + off
-            codes = jnp.clip(jnp.round(acc), clo, chi)
-            return codes.sum(axis=0)                     # exact int sum
+            return mac(jnp.stack(pats), *ops)      # (B*EF, M) code sums
 
-        jitted = jax.jit(fn)
-        return lambda st: jitted(st, w8)
+        return functools.partial(jax.jit(fn), operands)
 
     def _tail_np(self, out: np.ndarray) -> np.ndarray:
         """Block-tail M-type program: dequantization (quantized engines),

@@ -12,6 +12,10 @@ Tiling: x (bm, n_c) and w (n_c, bn) blocks live in VMEM; the f32 output
 block doubles as the code accumulator (codes are integers, exactly
 representable in f32 far beyond any realistic K).  MXU-aligned defaults:
 bm = bn = 256, n_c = 256.
+
+The kernel is compiled (Mosaic) exactly when JAX's default backend is a
+TPU and runs in Pallas interpret mode everywhere else — see
+:func:`interpret_mode`; no caller chooses.
 """
 from __future__ import annotations
 
@@ -20,17 +24,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU compiler params are a no-op under interpret mode
-    from jax.experimental.pallas import tpu as pltpu
+from repro.core.cim import CIMSpec, DEFAULT_SPEC, adc_convert_jnp
 
-    _COMPILER_PARAMS = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")
-    )
-except Exception:  # pragma: no cover - non-TPU builds
-    _COMPILER_PARAMS = None
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
-from repro.core.cim import CIMSpec, DEFAULT_SPEC
+
+def interpret_mode() -> bool:
+    """True when the kernel runs in Pallas interpret mode: the default
+    backend is not a TPU (the CPU test and validation target)."""
+    return jax.default_backend() != "tpu"
+
+
+def _codes(d, inv, q_max: int, off=None):
+    """One subarray's SAR conversion as f32 codes (exact integers)."""
+    return adc_convert_jnp(d, inv, -float(q_max + 1), float(q_max),
+                           off).astype(jnp.float32)
 
 
 def _cim_kernel(x_ref, w_ref, o_ref, *, nk: int, inv_step: float, step: float,
@@ -48,13 +59,9 @@ def _cim_kernel(x_ref, w_ref, o_ref, *, nk: int, inv_step: float, step: float,
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
-    # SAR ADC: round & saturate to adc_bits codes
-    codes = jnp.clip(
-        jnp.round(d.astype(jnp.float32) * inv_step),
-        -float(q_max + 1), float(q_max),
-    )
-    # digital accumulation of codes (integers — exact in f32)
-    o_ref[...] += codes
+    # SAR ADC: round & saturate to adc_bits codes, then digital
+    # accumulation of codes (integers — exact in f32)
+    o_ref[...] += _codes(d, inv_step, q_max)
 
     if not emit_codes:
         @pl.when(k == nk - 1)
@@ -65,10 +72,11 @@ def _cim_kernel(x_ref, w_ref, o_ref, *, nk: int, inv_step: float, step: float,
 def _cim_kernel_var(x_ref, w_ref, adc_ref, o_ref, *, nk: int, step: float,
                     q_max: int, emit_codes: bool):
     """The device-variation flavor: K step ``k``'s subarray converts with
-    its OWN per-ADC ``(inverse step, offset)`` pair, streamed in as a
-    (1, 2) f32 block — the same f32 multiply(+add)/round/saturate ops as
-    the numpy :func:`repro.core.cim.adc_convert`, so codes stay bitwise
-    across backends under a ``VariationModel``."""
+    its OWN per-ADC ``(inverse step, offset)`` pair, row ``k`` of the
+    whole (nk, 2) f32 table held in SMEM (a (1, 2) VMEM block violates
+    the TPU's (8, 128) block tiling) — the same f32 multiply(+add)/
+    round/saturate ops as the numpy :func:`repro.core.cim.adc_convert`,
+    so codes stay bitwise across backends under a ``VariationModel``."""
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -80,11 +88,7 @@ def _cim_kernel_var(x_ref, w_ref, adc_ref, o_ref, *, nk: int, step: float,
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
-    codes = jnp.clip(
-        jnp.round(d.astype(jnp.float32) * adc_ref[0, 0] + adc_ref[0, 1]),
-        -float(q_max + 1), float(q_max),
-    )
-    o_ref[...] += codes
+    o_ref[...] += _codes(d, adc_ref[k, 0], q_max, adc_ref[k, 1])
 
     if not emit_codes:
         @pl.when(k == nk - 1)
@@ -94,25 +98,23 @@ def _cim_kernel_var(x_ref, w_ref, adc_ref, o_ref, *, nk: int, step: float,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("spec", "block_m", "block_n", "interpret", "emit_codes"),
+    static_argnames=("spec", "block_m", "block_n", "emit_codes"),
 )
 def cim_matmul_pallas(xq: jax.Array, wq: jax.Array,
                       spec: CIMSpec = DEFAULT_SPEC,
                       block_m: int = 256, block_n: int = 256,
-                      interpret: bool = True,
                       emit_codes: bool = False,
                       adc_var: "jax.Array | None" = None) -> jax.Array:
     """(M, K) int8 @ (K, N) int8 -> (M, N) f32 through the CIM pipeline.
 
     Pads every dim to its block multiple; K blocks are ``spec.n_c`` wide so
-    each K-step is one subarray.  ``interpret=True`` runs the kernel body
-    in Python on CPU (validation target); on a real TPU pass False.
-    ``emit_codes=True`` skips the final step scaling and returns the raw
-    digitally-accumulated ADC code sums (integers in f32) — the quantity
-    the engine layer accumulates along a tile chain.  ``adc_var`` is an
-    optional (nk, 2) f32 array of per-subarray ``[inverse step, offset]``
-    ADC parameters (device variation); K step ``k`` reads row ``k``.  It
-    is a traced operand, so Monte-Carlo trials reuse one compiled kernel.
+    each K-step is one subarray.  ``emit_codes=True`` skips the final
+    step scaling and returns the raw digitally-accumulated ADC code sums
+    (integers in f32) — the quantity the engine layer accumulates along
+    a tile chain.  ``adc_var`` is an optional (nk, 2) f32 array of
+    per-subarray ``[inverse step, offset]`` ADC parameters (device
+    variation); K step ``k`` reads row ``k``.  It is a traced operand,
+    so Monte-Carlo trials reuse one compiled kernel.
     """
     m, k_dim = xq.shape
     k2, n = wq.shape
@@ -146,11 +148,10 @@ def cim_matmul_pallas(xq: jax.Array, wq: jax.Array,
             _cim_kernel_var, nk=nk, step=spec.adc_step,
             q_max=spec.q_max, emit_codes=emit_codes,
         )
-        in_specs.append(pl.BlockSpec((1, 2), lambda i, j, k: (k, 0)))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         operands = (xq, wq, adc_var.astype(jnp.float32))
-    kwargs = {}
-    if _COMPILER_PARAMS is not None and not interpret:
-        kwargs["compiler_params"] = _COMPILER_PARAMS
+    interpret = interpret_mode()
+    kwargs = {} if interpret else {"compiler_params": _COMPILER_PARAMS}
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -166,7 +167,6 @@ def cim_matmul_pallas(xq: jax.Array, wq: jax.Array,
 def cim_chain_codes_pallas(xq: jax.Array, wq: jax.Array,
                            spec: CIMSpec = DEFAULT_SPEC,
                            block_m: int = 256, block_n: int = 256,
-                           interpret: bool = True,
                            adc_var: "jax.Array | None" = None) -> jax.Array:
     """Multi-tile ``emit_codes`` invocation: one kernel call for a whole
     tile chain.
@@ -183,8 +183,7 @@ def cim_chain_codes_pallas(xq: jax.Array, wq: jax.Array,
     assert xq.shape[1] == wq.shape[0] and xq.shape[1] % spec.n_c == 0, (
         xq.shape, wq.shape, spec.n_c)
     return cim_matmul_pallas(
-        xq, wq, spec, block_m=block_m, block_n=block_n,
-        interpret=interpret, emit_codes=True,
+        xq, wq, spec, block_m=block_m, block_n=block_n, emit_codes=True,
         adc_var=None if adc_var is None else jnp.asarray(adc_var))
 
 

@@ -17,10 +17,6 @@ from repro.core.cim import CIMSpec, DEFAULT_SPEC, cim_matmul, quantize_symmetric
 from repro.kernels.cim_matmul import cim_matmul_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("spec", "use_pallas", "activation"))
 def cim_linear(x: jax.Array, wq: jax.Array, w_scale: jax.Array,
                bias: Optional[jax.Array] = None,
@@ -29,16 +25,16 @@ def cim_linear(x: jax.Array, wq: jax.Array, w_scale: jax.Array,
                activation: Optional[str] = None) -> jax.Array:
     """x (..., K) float @ pre-quantized wq (K, N) int8 -> (..., N) float.
 
-    use_pallas=True routes through the Pallas kernel (interpret mode off
-    TPU is slow for big shapes — the pure-jnp path has identical numerics,
-    proven by tests, and is the default on CPU).
+    use_pallas=True routes through the Pallas kernel (interpreted off
+    TPU, which is slow for big shapes — the pure-jnp path has identical
+    numerics, proven by tests, and is the default).
     """
     orig_dtype = x.dtype
     lead = x.shape[:-1]
     xq, x_scale = quantize_symmetric(x.astype(jnp.float32), spec.a_bits)
     if use_pallas:
         x2 = xq.reshape(-1, xq.shape[-1])
-        acc = cim_matmul_pallas(x2, wq, spec, interpret=not _on_tpu())
+        acc = cim_matmul_pallas(x2, wq, spec)
         acc = acc.reshape(*lead, -1)
     else:
         acc = cim_matmul(xq, wq, spec)

@@ -72,7 +72,17 @@ def cnn_forward(params, images, cnn: CNNConfig,
     activation — the tensor the Domino block would stream — keyed by
     layer name; the quantized PE engines calibrate their per-layer
     activation scale and ADC gain from it (``core/engine.py``).
+
+    Convolutions and matmuls run at full f32 precision on every backend
+    (a TPU's default f32 dot takes bf16 passes), so a chip calibrates
+    the same ADC gains and gives the same reference as the host.
     """
+    with jax.default_matmul_precision("highest"):
+        return _forward(params, images, cnn, cim, capture)
+
+
+def _forward(params, images, cnn: CNNConfig, cim: Optional[CIMSpec],
+             capture: Optional[Dict[str, jax.Array]]) -> jax.Array:
     x = images
     saved: Dict[str, jax.Array] = {}
     layers: List = list(cnn.layers)

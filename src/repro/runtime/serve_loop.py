@@ -280,6 +280,9 @@ class StreamServeReport:
     #: sweep, bounded by ``batch_window``); mirrors the
     #: ``serve_batch_size`` metrics histogram
     batch_sizes: Tuple[int, ...] = ()
+    #: (T, classes) per-request logits, frame-indexed (None when no
+    #: request arrived)
+    logits: Optional[np.ndarray] = field(default=None, repr=False)
 
     @property
     def latency_s(self) -> np.ndarray:
@@ -446,7 +449,8 @@ def serve_stream(sim, frames: np.ndarray,
         offered_inf_s=clock_hz / spacing, throughput_inf_s=throughput,
         clock_hz=clock_hz, latency_hist=(counts, edges),
         flagged_frames=tuple(mon.flagged_steps),
-        straggler_escalate=escalate, batch_sizes=res.batch_sizes)
+        straggler_escalate=escalate, batch_sizes=res.batch_sizes,
+        logits=res.logits)
     if metrics is not None:
         _export_serve_metrics(metrics, dict(metric_labels or {}),
                               report, res)

@@ -44,7 +44,7 @@ def test_pallas_matches_ref(m, k, n):
     wq = _rand_int8(k2, (k, n))
     spec = CIMSpec(n_c=256, adc_bits=8, gain=16.0)
     ref = cim_matmul_ref(xq, wq, spec)
-    out = cim_matmul_pallas(xq, wq, spec, interpret=True)
+    out = cim_matmul_pallas(xq, wq, spec)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=0, atol=0)
 
 
@@ -56,8 +56,7 @@ def test_pallas_block_shapes(block_m, block_n):
     wq = _rand_int8(k2, (768, 192))
     spec = CIMSpec()
     ref = cim_matmul_ref(xq, wq, spec)
-    out = cim_matmul_pallas(xq, wq, spec, block_m=block_m, block_n=block_n,
-                            interpret=True)
+    out = cim_matmul_pallas(xq, wq, spec, block_m=block_m, block_n=block_n)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -70,7 +69,7 @@ def test_pallas_spec_sweep(n_c, adc_bits):
     wq = _rand_int8(k2, (2 * n_c + 17, 96))
     spec = CIMSpec(n_c=n_c, adc_bits=adc_bits, gain=8.0)
     ref = cim_matmul_ref(xq, wq, spec)
-    out = cim_matmul_pallas(xq, wq, spec, interpret=True)
+    out = cim_matmul_pallas(xq, wq, spec)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -126,12 +125,12 @@ def test_pallas_codes_bitwise_vs_jnp_ragged(m, k, n):
     spec = CIMSpec(n_c=128, adc_bits=6, gain=5.0)
     codes_ref = np.asarray(_jnp_adc_codes(xq, wq, spec), np.int32)
     codes_pl = np.asarray(
-        cim_matmul_pallas(xq, wq, spec, interpret=True, emit_codes=True))
+        cim_matmul_pallas(xq, wq, spec, emit_codes=True))
     assert np.all(codes_pl == np.round(codes_pl))  # integers in f32
     assert codes_pl.astype(np.int32).tobytes() == codes_ref.tobytes()
     # and the step-scaled outputs are bitwise-equal f32 too
     out_jnp = np.asarray(cim_matmul(xq, wq, spec))
-    out_pl = np.asarray(cim_matmul_pallas(xq, wq, spec, interpret=True))
+    out_pl = np.asarray(cim_matmul_pallas(xq, wq, spec))
     assert out_jnp.tobytes() == out_pl.tobytes()
 
 
@@ -170,3 +169,34 @@ def test_quantize_roundtrip():
     back = np.asarray(q, np.float32) * np.asarray(s)
     rel = np.abs(back - np.asarray(x)).max() / np.abs(np.asarray(x)).max()
     assert rel < 1 / 100  # 8-bit: ~1/254 max relative step
+
+
+#: a subarray dot whose offset conversion sits on a half-code boundary:
+#: f32(f32(d * inv) + off) is exactly -57.5 (-> -58, half to even), while
+#: one fused multiply-add gives -57.4999973 (-> -57)
+_TIE_DOT, _TIE_INV, _TIE_OFF = -31146, 0.0018416885, -0.13876641
+
+
+@pytest.mark.parametrize("path", ["kernel", "jit"])
+def test_variation_conversion_rounds_twice(path):
+    """The variation conversion rounds the product and the offset sum
+    separately, as the numpy ``adc_convert`` does, on the Pallas kernel
+    and under jit — never as one fused multiply-add."""
+    from repro.core.cim import adc_convert, adc_convert_jnp
+
+    inv, off = np.float32(_TIE_INV), np.float32(_TIE_OFF)
+    want = adc_convert(np.array([_TIE_DOT]), inv, -128.0, 127.0, off)[0]
+    assert want == -58.0
+    if path == "kernel":
+        # 127*-128 + 127*-128 + 127*10 + 96*1 = -31146
+        xq = jnp.zeros((1, 256), jnp.int8).at[0, :4].set(
+            jnp.array([127, 127, 127, 96], jnp.int8))
+        wq = jnp.zeros((256, 1), jnp.int8).at[:4, 0].set(
+            jnp.array([-128, -128, 10, 1], jnp.int8))
+        got = cim_matmul_pallas(xq, wq, CIMSpec(), emit_codes=True,
+                                adc_var=jnp.array([[inv, off]]))[0, 0]
+    else:
+        convert = jax.jit(
+            lambda d, i, o: adc_convert_jnp(d, i, -128.0, 127.0, o))
+        got = convert(jnp.full((8, 128), _TIE_DOT, jnp.int32), inv, off)[0, 0]
+    assert float(got) == want

@@ -153,7 +153,7 @@ def test_pallas_jnp_codes_bitwise_property(m, k, n, seed):
     wq = _rand_int8(k2, (k, n))
     spec = CIMSpec(n_c=96, adc_bits=8, gain=7.0)
     out_jnp = np.asarray(cim_matmul(xq, wq, spec))
-    out_pl = np.asarray(cim_matmul_pallas(xq, wq, spec, interpret=True))
+    out_pl = np.asarray(cim_matmul_pallas(xq, wq, spec))
     assert out_jnp.tobytes() == out_pl.tobytes()
 
 

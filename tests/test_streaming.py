@@ -176,6 +176,8 @@ def test_serve_stream_report():
     assert counts.sum() == len(frames)
     pct = rep.latency_percentiles()
     assert pct["p50"] == pct["p99"] == rep.fill_latency
+    # each request's answer is the sequential run's, bit for bit
+    assert rep.logits.tobytes() == sim.run(frames).logits.tobytes()
     # oversubscribed queue: latency grows linearly with position
     hot = serve_stream(sim, frames, offered_inf_s=4 * rep.clock_hz / ii)
     assert hot.latency_cycles[-1] > hot.latency_cycles[0]

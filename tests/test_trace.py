@@ -232,12 +232,12 @@ def test_network_nonconforming_residual_rejected():
 
 
 def _jax_reference(cnn, params, x):
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.models.cnn import cnn_forward
 
-    with enable_x64():
+    with jax.enable_x64(True):
         p64 = {k: jnp.asarray(v, jnp.float64) for k, v in params.items()}
         return np.asarray(cnn_forward(p64, jnp.asarray(x, jnp.float64), cnn))
 
