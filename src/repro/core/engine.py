@@ -55,6 +55,7 @@ from repro.core.cim import (
     quantize_symmetric,
 )
 from repro.core.variation import VariationModel
+from repro.telemetry.spans import count_device_call, span
 
 #: engine registry keys accepted by ``make_engine`` / ``NetworkSimulator``
 ENGINES = ("exact", "cim", "pallas")
@@ -471,8 +472,11 @@ class CIMEngine(PEEngine):
         else:
             # per-layer w_bits below the serving format's 8: requantize
             # from the float weights onto the narrower grid
-            q, s = quantize_weight(weights, spec.w_bits)
-        q = self._perturbed(name, q, spec)
+            with span("engine.quantize_w", cat="engine", layer=name):
+                q, s = quantize_weight(weights, spec.w_bits)
+        with span("engine.draw", cat="engine", layer=name):
+            q = self._perturbed(name, q, spec)
+            adc_inv, adc_off = self._adc_variation(name, len(tiles), spec)
         tile_q = [
             np.ascontiguousarray(
                 q[tt.tap_row, tt.tap_col:tt.tap_col + tt.pack,
@@ -496,7 +500,6 @@ class CIMEngine(PEEngine):
         w_stack = np.zeros((len(tiles), max(kc), m), dot_dt)
         for i, tq in enumerate(tile_q):
             w_stack[i, :kc[i]] = tq.reshape(kc[i], m)
-        adc_inv, adc_off = self._adc_variation(name, len(tiles), spec)
         return ConvHandle(
             name=name, c_out=m,
             tile_w=[tq.astype(np.float64) for tq in tile_q],
@@ -513,12 +516,14 @@ class CIMEngine(PEEngine):
             q, s = np.asarray(prequant[0]), np.asarray(prequant[1])
             s = np.asarray(s, np.float64).reshape(-1)
         else:
-            q, s = quantize_weight(w, spec.w_bits)
-        q = self._perturbed(name, q, spec)
+            with span("engine.quantize_w", cat="engine", layer=name):
+                q, s = quantize_weight(w, spec.w_bits)
         # one physical per-subarray ADC every n_c weight rows; grid tiles
         # index into this shared pool by k0 // n_c (see fc_mac)
         n_alloc = 2 * math.ceil(q.shape[0] / spec.n_c) + 1
-        adc_inv, adc_off = self._adc_variation(name, n_alloc, spec)
+        with span("engine.draw", cat="engine", layer=name):
+            q = self._perturbed(name, q, spec)
+            adc_inv, adc_off = self._adc_variation(name, n_alloc, spec)
         return FCHandle(name=name, w=q.astype(np.float64),
                         w8=q.astype(np.int8),
                         adc_inv=adc_inv, adc_off=adc_off,
@@ -582,7 +587,9 @@ class CIMEngine(PEEngine):
         traceable, ``x`` the (T, R, max kc) int8 patch stack, and the
         result the (R, M) code sums.  One batched ``lax.dot_general``
         with int32 accumulation, the shared f32 ADC conversion, and an
-        exact int32 code sum (a zero sum is +0.0 on the host)."""
+        exact int32 code sum (a zero sum is +0.0 on the host), all under
+        the ``cim_mac`` named scope."""
+        import jax
         import jax.numpy as jnp
         from jax import lax
 
@@ -598,9 +605,10 @@ class CIMEngine(PEEngine):
         clo, chi = np.float32(h.code_lo), np.float32(h.code_hi)
 
         def fn(x, w8):
-            d = lax.dot_general(x, w8, (((2,), (1,)), ((0,), (0,))),
-                                preferred_element_type=jnp.int32)
-            return adc_convert_jnp(d, inv, clo, chi, off).sum(axis=0)
+            with jax.named_scope("cim_mac"):
+                d = lax.dot_general(x, w8, (((2,), (1,)), ((0,), (0,))),
+                                    preferred_element_type=jnp.int32)
+                return adc_convert_jnp(d, inv, clo, chi, off).sum(axis=0)
 
         return fn, (h.w8_stack,)
 
@@ -662,6 +670,7 @@ class PallasEngine(CIMEngine):
         codes = cim_matmul_pallas(
             jnp.asarray(xq8), jnp.asarray(wq8), spec, emit_codes=True,
             adc_var=None if adc_var is None else jnp.asarray(adc_var))
+        count_device_call((xq8, wq8, adc_var), codes)
         return np.asarray(codes, np.float64)
 
     def tile_mac(self, h, t, taps, quantized=False):
@@ -706,11 +715,14 @@ class PallasEngine(CIMEngine):
         x[:, :, :kcm] = patches.transpose(1, 0, 2)
         codes = cim_chain_codes_pallas(x.reshape(r, t * n_c), w, h.spec,
                                        adc_var=av)
+        count_device_call((x, w, av), codes)
         return np.asarray(codes, np.float64)
 
     def tiles_mac_fn(self, h):
         """The jit flavor of :meth:`tiles_mac`: the same n_c-block
-        layout, built on the traced patch stack, into the same kernel."""
+        layout, built on the traced patch stack, into the same kernel
+        (under the ``cim_mac`` named scope)."""
+        import jax
         import jax.numpy as jnp
 
         from repro.kernels.cim_matmul import cim_chain_codes_pallas
@@ -721,7 +733,8 @@ class PallasEngine(CIMEngine):
             t, r, kcm = x.shape
             x = jnp.pad(x, ((0, 0), (0, 0), (0, spec.n_c - kcm)))
             x = x.transpose(1, 0, 2).reshape(r, t * spec.n_c)
-            return cim_chain_codes_pallas(x, w, spec, adc_var=av)
+            with jax.named_scope("cim_mac"):
+                return cim_chain_codes_pallas(x, w, spec, adc_var=av)
 
         return fn, self._chain_operands(h)
 

@@ -98,7 +98,7 @@ from repro.core.schedule import (
 )
 from repro.core.simulator import BlockSimulator, SimCounters, simulate_fc
 from repro.core.trace import TracePlan, TraceExecutor, compile_trace
-from repro.telemetry.spans import span
+from repro.telemetry.spans import count, span
 from repro.core.transport import (
     OFM,
     RESIDUAL,
@@ -546,9 +546,11 @@ class NetworkSimulator:
                                   counters).run(x, **kw)
         layer = self.cnn.layers[li]
         b, p = x.shape[0], layer.p
-        padded = np.zeros((b, layer.h + 2 * p, layer.w + 2 * p, layer.c),
-                          np.float64)
-        padded[:, p:p + layer.h, p:p + layer.w] = x
+        with span("net.pad", cat="network", layer=layer.name):
+            padded = np.zeros(
+                (b, layer.h + 2 * p, layer.w + 2 * p, layer.c), np.float64)
+            count("scratch_alloc_bytes", padded.nbytes)
+            padded[:, p:p + layer.h, p:p + layer.w] = x
         outs = [
             self._executor(li, si, strip.sched, transport, counters)
             .run(padded[:, :, strip.lo:strip.hi], **kw)
@@ -683,8 +685,9 @@ class NetworkSimulator:
                                           placement.block_end[li], res_bytes)
                 shortcut = block_in
             # tail adder + activation after the shortcut join
-            y = y + shortcut
-            y = np.maximum(y, 0.0)
+            with span("net.residual", cat="network", layer=layer.name):
+                y = y + shortcut
+                y = np.maximum(y, 0.0)
             counters.act_ops += y.shape[1] * y.shape[2] * y.shape[3]
         return y
 
@@ -793,10 +796,12 @@ class NetworkSimulator:
         traffic = [TrafficCounters() for _ in range(t_n)]
         if batched:
             logits, batch_sizes = self._stream_numerics(frames, chunk)
-            for t in range(t_n):
-                self._account_frame(counters[t], traffic[t])
-            start, finish = stream_timeline(arr, occ, lat)
-            fifo_depth = self._residual_fifo_depth(t_n)
+            with span("net.account", cat="network", frames=t_n):
+                for t in range(t_n):
+                    self._account_frame(counters[t], traffic[t])
+            with span("net.timeline", cat="network", frames=t_n):
+                start, finish = stream_timeline(arr, occ, lat)
+                fifo_depth = self._residual_fifo_depth(t_n)
         else:
             logits, start, finish, fifo_depth = self._stream_percell(
                 frames, arr, occ, lat, counters, traffic)

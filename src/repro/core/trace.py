@@ -74,7 +74,7 @@ from repro.core.instructions import BUF_PUSH, FROM_PE, Instruction, Port
 from repro.core.schedule import BlockSchedule
 from repro.core.simulator import SimCounters, _standalone_transport
 from repro.core.transport import CHAIN, GROUP, PSUM_BYTES, NoCTransport
-from repro.telemetry.spans import span
+from repro.telemetry.spans import count, count_device_call, span
 
 
 @dataclass(frozen=True)
@@ -227,6 +227,7 @@ class TraceExecutor:
                 and buf.dtype == np.dtype(dtype):
             return buf
         buf = np.zeros(shape, dtype)
+        count("scratch_alloc_bytes", buf.nbytes)
         if buf.size <= self._SCRATCH_CAP_ELEMS:
             self._scratch[key] = buf
         return buf
@@ -248,9 +249,10 @@ class TraceExecutor:
         if self.use_jax and self.engine.name == "exact":
             out = self._run_jax(ifm)
         else:
-            padded = self._scratch_buf(
-                "padded", (b, s.hp, s.wp, s.c_in), np.float64)
-            padded[:, s.pad:s.pad + s.h, s.pad:s.pad + s.w] = ifm
+            with span("te.pad", cat="trace", layer=s.layer_name):
+                padded = self._scratch_buf(
+                    "padded", (b, s.hp, s.wp, s.c_in), np.float64)
+                padded[:, s.pad:s.pad + s.h, s.pad:s.pad + s.w] = ifm
             stream = padded.reshape(b, -1, s.c_in)
             if not self.fused:
                 out = self._execute_np(stream)
@@ -345,16 +347,19 @@ class TraceExecutor:
         engine, handle = self.engine, self.handle
         # quantized codes are int8-ranged by construction — the compact
         # view moves 8x fewer bytes through the gathers
-        qs = engine.quant_stream(handle, stream).astype(np.int8)
+        with span("te.quant", cat="trace", layer=s.layer_name):
+            qs = engine.quant_stream(handle, stream).astype(np.int8)
         b, ef, m = qs.shape[0], self.plan.fires, s.c_out
         out = np.empty((b, ef, m), np.float64)
         kcm = max(self.handle.kc)
         for lo, hi in self._quant_chunks(ef, b):
-            buf = self._scratch_buf(
-                "qbuf", (len(self.plan.tiles), b * (hi - lo), kcm),
-                self.handle.w_stack.dtype)
-            buf = self._gather_tiles(qs, lo, hi, buf)
-            codes = engine.tiles_mac(handle, buf)    # (B*rows, M) code sums
+            with span("te.gather", cat="trace", layer=s.layer_name):
+                buf = self._scratch_buf(
+                    "qbuf", (len(self.plan.tiles), b * (hi - lo), kcm),
+                    self.handle.w_stack.dtype)
+                buf = self._gather_tiles(qs, lo, hi, buf)
+            with span("te.mac", cat="trace", layer=s.layer_name):
+                codes = engine.tiles_mac(handle, buf)  # (B*rows, M) sums
             out[:, lo:hi] = codes.reshape(b, hi - lo, m)
         return self._tail_np(out.reshape(b, s.e, s.f, m))
 
@@ -370,14 +375,21 @@ class TraceExecutor:
         is *bitwise* equal to the numpy fused/per-tile paths (codes are
         < 2^24, exact in f32)."""
         s = self.sched
-        qs = self.engine.quant_stream(self.handle, stream)
+        with span("te.quant", cat="trace", layer=s.layer_name):
+            qs8 = self.engine.quant_stream(self.handle, stream).astype(
+                np.int8)
         if self._jax_fn is None:
             with span(f"jit_build:{self.sched.layer_name}", cat="jit"):
                 self._jax_fn = self._build_jax_qfn()
-        csum = self._jax_fn(qs.astype(np.int8))
+        # the step returns once dispatched (its numpy operands copied to
+        # the device); the fetch then waits for it and copies back
+        with span("te.step", cat="trace", layer=s.layer_name):
+            csum = self._jax_fn(qs8)
+        with span("te.fetch", cat="trace", layer=s.layer_name):
+            out = np.asarray(csum, np.float64)
+        count_device_call((qs8, *self._jax_fn.args[0]), csum)
         b = stream.shape[0]
-        out = np.asarray(csum, np.float64).reshape(b, s.e, s.f, s.c_out)
-        return self._tail_np(out)
+        return self._tail_np(out.reshape(b, s.e, s.f, s.c_out))
 
     def _build_jax_qfn(self):
         """The jitted step with the engine's operands bound first:
@@ -394,14 +406,15 @@ class TraceExecutor:
         def fn(ops, stream):
             b = stream.shape[0]
             pats = []
-            for i, tt in enumerate(plan.tiles):
-                p = jnp.take(stream, tt.gather, axis=1)  # (B, pack, EF, C)
-                p = p[..., tt.c_lo:tt.c_hi].transpose(0, 2, 1, 3)
-                p = p.reshape(b * ef, kcs[i])
-                if kcs[i] < kcm:
-                    p = jnp.pad(p, ((0, 0), (0, kcm - kcs[i])))
-                pats.append(p)
-            return mac(jnp.stack(pats), *ops)      # (B*EF, M) code sums
+            with jax.named_scope("te_step"):
+                for i, tt in enumerate(plan.tiles):
+                    p = jnp.take(stream, tt.gather, axis=1)  # (B,pack,EF,C)
+                    p = p[..., tt.c_lo:tt.c_hi].transpose(0, 2, 1, 3)
+                    p = p.reshape(b * ef, kcs[i])
+                    if kcs[i] < kcm:
+                        p = jnp.pad(p, ((0, 0), (0, kcm - kcs[i])))
+                    pats.append(p)
+                return mac(jnp.stack(pats), *ops)  # (B*EF, M) code sums
 
         return functools.partial(jax.jit(fn), operands)
 
@@ -410,27 +423,28 @@ class TraceExecutor:
         bias, activation, Fig. 9 pooling — each fold replayed in the
         interpreter's operand order."""
         s = self.sched
-        b = out.shape[0]
-        out = self.engine.finalize_conv(self.handle, out)
-        if self.bias is not None:
-            out = out + self.bias
-        if s.tail.activation == "relu":
-            out = np.maximum(out, 0.0)
-        ps = s.tail.pool_s
-        if ps:
-            assert s.e % ps == 0 and s.f % ps == 0, (
-                f"pooling {ps} does not tile the {s.e}x{s.f} OFM")
-            win = out.reshape(b, s.e // ps, ps, s.f // ps, ps, s.c_out)
-            # running row max in y order (POOL_STORE then POOL_MAX ...)
-            row = win[:, :, :, :, 0]
-            for y in range(1, ps):
-                row = np.maximum(row, win[:, :, :, :, y])
-            # fold window rows in x order (row buffer merge, POOL_OUT)
-            res = row[:, :, 0]
-            for x in range(1, ps):
-                res = np.maximum(res, row[:, :, x])
-            out = res
-        return out
+        with span("te.tail", cat="trace", layer=s.layer_name):
+            b = out.shape[0]
+            out = self.engine.finalize_conv(self.handle, out)
+            if self.bias is not None:
+                out = out + self.bias
+            if s.tail.activation == "relu":
+                out = np.maximum(out, 0.0)
+            ps = s.tail.pool_s
+            if ps:
+                assert s.e % ps == 0 and s.f % ps == 0, (
+                    f"pooling {ps} does not tile the {s.e}x{s.f} OFM")
+                win = out.reshape(b, s.e // ps, ps, s.f // ps, ps, s.c_out)
+                # running row max in y order (POOL_STORE then POOL_MAX ...)
+                row = win[:, :, :, :, 0]
+                for y in range(1, ps):
+                    row = np.maximum(row, win[:, :, :, :, y])
+                # fold window rows in x order (row buffer merge, POOL_OUT)
+                res = row[:, :, 0]
+                for x in range(1, ps):
+                    res = np.maximum(res, row[:, :, x])
+                out = res
+            return out
 
     # -- jax fast path (behind the flag; float32, approximate) ---------------
 

@@ -14,6 +14,7 @@ histograms — the serving-side view of the paper's stream computing.
 from __future__ import annotations
 
 import functools
+import itertools
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -355,6 +356,10 @@ def build_stream_sim(cnn, params: Dict[str, Any], engine=None,
 LATENCY_BUCKETS_CYCLES = (
     1e3, 2e3, 5e3, 1e4, 2e4, 5e4, 1e5, 2e5, 5e5, 1e6, 2e6, 5e6, 1e7)
 
+#: ids of ``serve_stream`` calls in this process, the ``call`` argument
+#: of each call's root span
+_SERVE_CALLS = itertools.count()
+
 
 def serve_stream(sim, frames: np.ndarray,
                  offered_inf_s: Optional[float] = None,
@@ -429,8 +434,9 @@ def serve_stream(sim, frames: np.ndarray,
                                   report, None)
         return report
     arrivals = np.floor(np.arange(t_n) * spacing).astype(np.int64)
+    # the call id ties every span of one call to this root span
     with _tspan(f"serve_stream:{sim.cnn.name}", frames=t_n,
-                batch_window=batch_window or 0):
+                batch_window=batch_window or 0, call=next(_SERVE_CALLS)):
         res = sim.run_stream(frames, arrivals=arrivals, chunk=batch_window)
     lat = res.frame_latency
     exits = res.finish[:, -1]

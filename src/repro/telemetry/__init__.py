@@ -9,8 +9,9 @@ serving loop and DSE:
   exact-integer conservation check against the counters *and* the
   energy model's routed byte-hops.
 * :mod:`repro.telemetry.spans` — nestable host wall-clock
-  :class:`Span`/:class:`Profiler` plus the streaming stage x frame
-  timeline, exported as Chrome trace-event JSON (Perfetto-viewable).
+  :class:`Span`/:class:`Profiler` with named counter totals (``count``)
+  plus the streaming stage x frame timeline, exported as Chrome
+  trace-event JSON (Perfetto-viewable).
 * :mod:`repro.telemetry.metrics` — Prometheus-style
   counters/gauges/histograms with labelled series and JSON snapshots,
   backing ``serve_stream``.
@@ -23,8 +24,8 @@ from repro.telemetry.heatmap import (FlowStats, LinkHeatmap, LinkRecorder,
 from repro.telemetry.metrics import (DEFAULT_BUCKETS, MetricFamily,
                                      MetricsRegistry)
 from repro.telemetry.spans import (Profiler, TRACE_PID_HOST, TRACE_PID_SIM,
-                                   active_profiler, chrome_trace,
-                                   load_chrome_trace, span,
+                                   active_profiler, chrome_trace, count,
+                                   count_device_call, load_chrome_trace, span,
                                    stream_timeline_events,
                                    validate_chrome_trace, write_chrome_trace)
 
@@ -33,6 +34,7 @@ __all__ = [
     "check_conservation", "record_run",
     "DEFAULT_BUCKETS", "MetricFamily", "MetricsRegistry",
     "Profiler", "TRACE_PID_HOST", "TRACE_PID_SIM", "active_profiler",
-    "chrome_trace", "load_chrome_trace", "span", "stream_timeline_events",
+    "chrome_trace", "count", "count_device_call", "load_chrome_trace", "span",
+    "stream_timeline_events",
     "validate_chrome_trace", "write_chrome_trace",
 ]

@@ -10,8 +10,9 @@ Subcommands::
 
     python -m repro.telemetry trace out.json --model vgg11-cifar10
         capture a Chrome trace of a short streaming serve: host spans
-        (lowering, calibration, jit) + the stage x frame pipeline
-        timeline; open the file in https://ui.perfetto.dev
+        (lowering, calibration, jit, the hot path's steps), the hot
+        path's counter totals + the stage x frame pipeline timeline;
+        open the file in https://ui.perfetto.dev
 
     python -m repro.telemetry summarize trace.json
         validate a trace file and print per-category span totals
@@ -115,7 +116,8 @@ def cmd_trace(args) -> int:
         serve_stream(sim, frames, metrics=registry)
     res = sim.run_stream(frames)  # timeline re-run outside the profiler
     stage_names = [cnn.layers[st.li].name for st in sim._stages]
-    events = prof.events + stream_timeline_events(res, stage_names)
+    events = prof.events + [prof.counts_event()] + \
+        stream_timeline_events(res, stage_names)
     errors = validate_chrome_trace(chrome_trace(events))
     if errors:
         print("INVALID TRACE:")

@@ -1,14 +1,23 @@
-"""Host-side wall-clock spans and Chrome trace-event JSON export.
+"""Host-side wall-clock spans, counters and Chrome trace-event JSON export.
 
 Two clocks feed one trace file:
 
 * **Host spans** — a nestable :class:`Profiler` records ``B``/``E``
   duration events in wall-clock microseconds around expensive host
   phases (quantization calibration, trace lowering, jit warmup, engine
-  swaps, DSE evaluations).  Instrumented call sites go through the
-  module-level :func:`span` helper, which returns a shared null context
-  manager when no profiler is installed — the off-path cost is one
-  global read and an ``is None`` test, and *nothing* is allocated.
+  swaps, DSE evaluations) and around each step of the hot path (the
+  trace executor's pad / quantize / dispatch / fetch / tail, the network
+  executor's residual adds and accounting replay).  Instrumented call
+  sites go through the module-level :func:`span` helper, which returns a
+  shared null context manager when no profiler is installed — the
+  off-path cost is one global read and an ``is None`` test, and
+  *nothing* is allocated.  :func:`count` adds to the installed
+  profiler's named totals (:attr:`Profiler.counts`: host<->device bytes,
+  scratch allocations, dispatches) at the same cost when off.  While a
+  profiler is installed, every garbage collection is recorded as a
+  ``gc`` span.  With ``Profiler(annotate=True)`` each span also enters a
+  ``jax.profiler.TraceAnnotation`` named ``"repro:" + name``, so it lands
+  in a JAX profiler trace on the device's clock.
 
 * **Simulator timelines** — :func:`stream_timeline_events` converts a
   :class:`repro.core.network.StreamResult` stage x frame ``start`` /
@@ -26,9 +35,12 @@ on (monotone ``ts``, LIFO-matched ``B``/``E`` pairs per thread).
 """
 from __future__ import annotations
 
+import gc
 import json
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 # Trace "process" ids: host wall-clock vs simulated mesh cycles.  They
 # are separate top-level groups in Perfetto so the two clock domains
@@ -70,8 +82,32 @@ def span(name: str, cat: str = "host", **args: Any):
     return p.span(name, cat, **args)
 
 
+def count(name: str, n: int) -> None:
+    """Add ``n`` to the active profiler's ``counts[name]``; a no-op with
+    no profiler installed."""
+    p = _ACTIVE
+    if p is not None:
+        p.counts[name] = p.counts.get(name, 0) + n
+
+
+def count_device_call(operands: Sequence[Any], result: Any) -> None:
+    """Count one call onto the device on the active profiler: one of
+    ``dispatches``, the bytes of the numpy arrays among ``operands`` as
+    ``h2d_bytes`` (what the call copies to the device; device arrays and
+    ``None`` count 0), and the bytes of the ``result`` fetched back as
+    ``d2h_bytes``.  A no-op with no profiler installed."""
+    p = _ACTIVE
+    if p is None:
+        return
+    c = p.counts
+    c["dispatches"] = c.get("dispatches", 0) + 1
+    c["h2d_bytes"] = c.get("h2d_bytes", 0) + sum(
+        a.nbytes for a in operands if isinstance(a, np.ndarray))
+    c["d2h_bytes"] = c.get("d2h_bytes", 0) + result.nbytes
+
+
 class _Span:
-    __slots__ = ("_prof", "_name", "_cat", "_args")
+    __slots__ = ("_prof", "_name", "_cat", "_args", "_ann")
 
     def __init__(self, prof: "Profiler", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -79,16 +115,24 @@ class _Span:
         self._name = name
         self._cat = cat
         self._args = args
+        self._ann = None
 
     def __enter__(self) -> "_Span":
+        prof = self._prof
         ev = {"name": self._name, "cat": self._cat, "ph": "B",
-              "ts": self._prof._now_us(), "pid": TRACE_PID_HOST, "tid": 1}
+              "ts": prof._now_us(), "pid": TRACE_PID_HOST, "tid": 1}
         if self._args:
             ev["args"] = dict(self._args)
-        self._prof.events.append(ev)
+        prof.events.append(ev)
+        if prof._annotation is not None:
+            self._ann = prof._annotation("repro:" + self._name)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc: object) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         self._prof.events.append(
             {"name": self._name, "cat": self._cat, "ph": "E",
              "ts": self._prof._now_us(), "pid": TRACE_PID_HOST, "tid": 1})
@@ -96,21 +140,35 @@ class _Span:
 
 
 class Profiler:
-    """Collects host-side trace events relative to its construction time.
+    """Collects host-side trace events relative to its construction time,
+    and named counter totals (:attr:`counts`).
 
     Use as a context manager (or call :meth:`install` / :meth:`uninstall`)
-    to make module-level :func:`span` calls route here::
+    to make module-level :func:`span` and :func:`count` calls route
+    here::
 
         with Profiler() as prof:
             sim = NetworkSimulator(...)      # calibration/lowering spans land
             sim.run(x)
-        write_chrome_trace("trace.json", prof.events)
+        write_chrome_trace("trace.json", prof.events + [prof.counts_event()])
+
+    ``annotate=True`` also enters a ``jax.profiler.TraceAnnotation``
+    (``"repro:" + name``) for each span, so a JAX profiler trace taken
+    meanwhile holds the spans on the device's clock; JAX is imported
+    only in this mode.
     """
 
-    def __init__(self, clock=time.perf_counter):
+    def __init__(self, clock=time.perf_counter, annotate: bool = False):
         self._clock = clock
         self._t0 = clock()
         self.events: List[Dict[str, Any]] = []
+        self.counts: Dict[str, int] = {}
+        self._annotation = None
+        if annotate:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
+        self._gc_open: List[_Span] = []
 
     def _now_us(self) -> float:
         return (self._clock() - self._t0) * 1e6
@@ -118,27 +176,32 @@ class Profiler:
     def span(self, name: str, cat: str = "host", **args: Any) -> _Span:
         return _Span(self, name, cat, args)
 
-    def instant(self, name: str, cat: str = "host", **args: Any) -> None:
-        ev = {"name": name, "cat": cat, "ph": "i", "s": "t",
-              "ts": self._now_us(), "pid": TRACE_PID_HOST, "tid": 1}
-        if args:
-            ev["args"] = dict(args)
-        self.events.append(ev)
+    def counts_event(self) -> Dict[str, Any]:
+        """The counter totals as one Chrome ``C`` event, stamped now."""
+        return {"name": "counts", "cat": "host", "ph": "C",
+                "ts": self._now_us(), "pid": TRACE_PID_HOST, "tid": 1,
+                "args": dict(self.counts)}
 
-    def counter(self, name: str, values: Dict[str, float],
-                ts_us: Optional[float] = None) -> None:
-        self.events.append(
-            {"name": name, "cat": "host", "ph": "C",
-             "ts": self._now_us() if ts_us is None else ts_us,
-             "pid": TRACE_PID_HOST, "tid": 1, "args": dict(values)})
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """``gc.callbacks`` hook: each collection becomes a ``gc`` span."""
+        if phase == "start":
+            s = _Span(self, "gc", "gc", {"generation": info["generation"]})
+            s.__enter__()
+            self._gc_open.append(s)
+        elif self._gc_open:
+            self._gc_open.pop().__exit__(None, None, None)
 
     def install(self) -> "Profiler":
         global _ACTIVE
         _ACTIVE = self
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
         return self
 
     def uninstall(self) -> None:
         global _ACTIVE
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
         if _ACTIVE is self:
             _ACTIVE = None
 

@@ -63,6 +63,24 @@ def test_stream_bitwise_equals_sequential(name, t_n):
         assert one.traffic.hops == res.frame_traffic[t].hops
 
 
+def test_stream_bitwise_under_profiler():
+    """The hot path's spans and counters change no bit: the pipelined
+    run under an installed profiler equals the sequential run without
+    one, logits and per-frame counters."""
+    from repro.telemetry.spans import Profiler
+
+    sim, frames = _stream_setup("resnet18-cifar10", 4)
+    seq = sim.run(frames)
+    with Profiler(annotate=False) as prof:
+        res = sim.run_stream(frames)
+    assert res.logits.tobytes() == seq.logits.tobytes()
+    for t in range(4):
+        one = sim.run(frames[t])
+        assert one.counters == res.frame_counters[t]
+        assert one.traffic.byte_hops == res.frame_traffic[t].byte_hops
+    assert any(e["name"] == "te.tail" for e in prof.events)
+
+
 def test_stream_residuals_cross_the_skew():
     """ResNet shortcuts are buffered across the pipeline skew (the
     paper's FIFO forwarding): with several frames in flight, more than

@@ -15,25 +15,34 @@ The contracts under test:
 * **Chrome traces** — emitted event streams are valid trace-event
   JSON: known phases, monotone timestamps, properly nested B/E pairs;
   the validator also rejects corrupted documents.
+* **Hot-path spans and counters** — the trace executor's steps nest
+  inside one ``serve_stream:`` root per call, the host->device byte
+  counter equals what the layer shapes say it must, garbage collections
+  are spans, and annotated spans reach the JAX profiler's own trace.
 * **Metrics registry** — Prometheus data-model semantics: idempotent
   family creation, labelled series, cumulative histogram buckets,
   JSON-serializable snapshots.
 """
+import gc
+import glob
 import json
+import math
+import os
 
 import numpy as np
 import pytest
 from conftest import int_params as _int_params
 
-from repro.configs.cnn import CNN_BENCHMARKS
+from repro.configs.cnn import CNN_BENCHMARKS, FCLayer
 from repro.core.energy import routed_byte_hops_per_class
 from repro.core.mapping import plan_network
 from repro.core.network import NetworkSimulator
 from repro.dse.placements import strategies
 from repro.runtime.serve_loop import serve_stream
-from repro.telemetry import (MetricsRegistry, Profiler, check_conservation,
-                             chrome_trace, record_run, span,
-                             stream_timeline_events, validate_chrome_trace)
+from repro.telemetry import (MetricsRegistry, Profiler, active_profiler,
+                             check_conservation, chrome_trace, count,
+                             record_run, span, stream_timeline_events,
+                             validate_chrome_trace)
 
 def _setup(name, batch=1, seed=0, **kw):
     rng = np.random.default_rng(seed)
@@ -167,10 +176,9 @@ def test_profiler_spans_nest_and_validate():
     with prof:
         with span("outer", cat="host", depth=0):
             with span("inner", cat="jit", depth=1):
-                pass
-            prof.instant("marker", cat="host")
-        prof.counter("queue", {"depth": 3})
-    doc = chrome_trace(prof.events)
+                count("queue", 3)
+        count("queue", 2)
+    doc = chrome_trace(prof.events + [prof.counts_event()])
     assert validate_chrome_trace(doc) == []
     names = [e["name"] for e in doc["traceEvents"]]
     assert names.count("outer") == 2 and names.count("inner") == 2
@@ -178,6 +186,145 @@ def test_profiler_spans_nest_and_validate():
     b_outer = next(e for e in doc["traceEvents"]
                    if e["name"] == "outer" and e["ph"] == "B")
     assert b_outer["args"] == {"depth": 0}
+    # the totals export as one counter event, after every span
+    c = doc["traceEvents"][-1]
+    assert c["ph"] == "C" and c["args"] == {"queue": 5}
+
+
+def test_count_totals_and_nothing_without_profiler():
+    """count() adds to the installed profiler's totals, and with no
+    profiler installed it records nothing anywhere."""
+    idle = Profiler()
+    assert active_profiler() is None
+    count("h2d_bytes", 10)          # no profiler: a no-op
+    with Profiler() as prof:
+        for n in (1, 2, 3):
+            count("h2d_bytes", n)
+        count("dispatches", 1)
+    count("h2d_bytes", 100)         # uninstalled again
+    assert prof.counts == {"h2d_bytes": 6, "dispatches": 1}
+    assert idle.counts == {} and idle.events == []
+
+
+def test_gc_spans_nest_and_validate():
+    """While a profiler is installed each garbage collection is a ``gc``
+    span carrying its generation, nested wherever it ran; uninstalling
+    removes the hook."""
+    prof = Profiler()
+    gc.disable()                    # only the explicit collections run
+    try:
+        with prof:
+            assert prof._on_gc in gc.callbacks
+            with span("outer"):
+                gc.collect()
+            gc.collect(0)
+        assert prof._on_gc not in gc.callbacks
+        gc.collect()                # not recorded
+    finally:
+        gc.enable()
+    doc = chrome_trace(prof.events)
+    assert validate_chrome_trace(doc) == []
+    # (parent names, generation) of each gc span, in order
+    stack, gcs = [], []
+    for e in prof.events:
+        if e["ph"] == "B":
+            if e["name"] == "gc":
+                gcs.append((list(stack), e["args"]["generation"]))
+            stack.append(e["name"])
+        elif e["ph"] == "E":
+            stack.pop()
+    assert gcs == [(["outer"], 2), ([], 0)]
+
+
+def test_annotated_spans_reach_the_jax_trace(tmp_path):
+    """Profiler(annotate=True) writes each span into the JAX profiler's
+    trace as a ``repro:`` host event, on the trace's own clock."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with Profiler(annotate=True):
+            with span("outer"):
+                with span("inner"):
+                    jnp.ones(3).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    files = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    data = ProfileData.from_file(files[0])
+    got = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro:"):
+                    got[ev.name] = (ev.start_ns, ev.duration_ns)
+    assert {"repro:outer", "repro:inner"} <= set(got)
+    (o0, od), (i0, idur) = got["repro:outer"], got["repro:inner"]
+    assert o0 <= i0 and i0 + idur <= o0 + od
+
+
+def test_hot_path_spans_nest_in_one_call_and_count_h2d():
+    """On the pallas jit path, every trace-executor and network span of a
+    ``serve_stream`` call lies inside that call's one root span, and the
+    host->device bytes are what the shapes say: per (layer, strip) the
+    int8 stream plus the kernel's (tiles x n_c, M) int8 weight operand;
+    per FC grid call its int8 input slice and weight block."""
+    from repro.runtime.serve_loop import build_stream_sim
+
+    rng = np.random.default_rng(3)
+    cnn = CNN_BENCHMARKS["resnet18-cifar10"]()
+    params = {k: v * 0.1 for k, v in _int_params(cnn, rng).items()}
+    frames = rng.random((2, 32, 32, 3))
+    sim = build_stream_sim(cnn, params, engine="pallas", trace_jit=True,
+                           calib_images=frames[:1])
+    plain = serve_stream(sim, frames)          # compiles outside the trace
+    with Profiler() as prof:
+        profiled = serve_stream(sim, frames)
+    assert profiled.logits.tobytes() == plain.logits.tobytes()
+    assert validate_chrome_trace(chrome_trace(prof.events)) == []
+
+    roots, stack, inside = [], [], {}
+    for e in prof.events:
+        if e["ph"] == "B":
+            if not stack:
+                roots.append(e)
+            elif e["name"].startswith(("te.", "net.")):
+                assert stack[0]["name"] == "serve_stream:resnet18-cifar10"
+                inside[e["name"]] = inside.get(e["name"], 0) + 1
+            stack.append(e)
+        elif e["ph"] == "E":
+            stack.pop()
+    assert [r["name"] for r in roots if r["name"] != "gc"] == [
+        "serve_stream:resnet18-cifar10"]
+    assert "call" in roots[0]["args"]
+    n_ex = len(sim._executors)
+    for name in ("te.pad", "te.quant", "te.step", "te.fetch", "te.tail"):
+        assert inside[name] == n_ex, name
+    assert inside["net.residual"] == 8 and inside["net.account"] == 1
+
+    b, n_c = len(frames), sim.pe_engine.spec.n_c
+    want_h2d = want_d2h = calls = 0
+    for ex in sim._executors.values():
+        sched = ex.sched
+        want_h2d += b * ex.plan.n_pix * sched.c_in \
+            + len(ex.plan.tiles) * n_c * sched.c_out
+        want_d2h += 4 * b * ex.plan.fires * sched.c_out
+        calls += 1
+    for layer in cnn.layers:
+        if not isinstance(layer, FCLayer):
+            continue
+        m_t, m_a = math.ceil(layer.c_in / n_c), math.ceil(layer.c_out
+                                                          / sim.n_m)
+        want_h2d += b * layer.c_in * m_a + layer.c_in * layer.c_out
+        want_d2h += 4 * b * layer.c_out * m_t
+        calls += m_t * m_a
+    assert prof.counts["h2d_bytes"] == want_h2d
+    assert prof.counts["d2h_bytes"] == want_d2h
+    assert prof.counts["dispatches"] == calls
+    # every scratch buffer was made in the first call
+    assert prof.counts.get("scratch_alloc_bytes", 0) == 0
 
 
 def test_stream_timeline_trace_is_valid():
