@@ -60,6 +60,11 @@ from repro.telemetry.spans import count_device_call, span
 #: engine registry keys accepted by ``make_engine`` / ``NetworkSimulator``
 ENGINES = ("exact", "cim", "pallas")
 
+#: elements per block of the blocked activation quantization
+#: (:meth:`CIMEngine.quant_stream` with ``out``): a 256 KiB float64 block
+#: buffer that stays in the core's cache across its four passes
+_QBLOCK_ELEMS = 1 << 15
+
 
 # ---------------------------------------------------------------------------
 # Weight quantization shared by every quantized consumer (engines, the
@@ -547,8 +552,35 @@ class CIMEngine(PEEngine):
         return adc_convert(d, h.adc_inv[i], h.code_lo, h.code_hi,
                            h.adc_off[i])
 
-    def quant_stream(self, h, x):
-        return self._quant(x, h)
+    def quant_stream(self, h, x, out=None):
+        """Without ``out``: :meth:`_quant` (int-valued float64).  With
+        ``out`` (int8, ``x``'s shape, any strides — the trace executor's
+        raster interior): one blocked pass writes the codes there, the
+        same bits as ``self._quant(x, h).astype(np.int8)``.  Each block of
+        ``_QBLOCK_ELEMS`` elements is divided, rounded half-to-even and
+        saturated in place in one reused float64 buffer, so no full-size
+        float64 temporary exists; the division is float64 even for a
+        float32 ``x``, as the float64 padded copy made it.  Saturation is
+        ``np.maximum`` then ``np.minimum``: ``np.clip``'s values at half
+        its cost."""
+        if out is None:
+            return self._quant(x, h)
+        assert out.shape == x.shape and out.dtype == np.int8, out
+        lo, hi = -h.a_clip - 1, h.a_clip
+        b, rows, row_shape = x.shape[0], x.shape[1], x.shape[2:]
+        row = math.prod(row_shape)
+        step = max(1, _QBLOCK_ELEMS // row)
+        tmp = np.empty(min(rows, step) * row, np.float64)
+        for i in range(b):
+            for r0 in range(0, rows, step):
+                r1 = min(rows, r0 + step)
+                blk = tmp[:(r1 - r0) * row].reshape((r1 - r0,) + row_shape)
+                np.divide(x[i, r0:r1], h.a_scale, out=blk, dtype=np.float64)
+                np.rint(blk, out=blk)
+                np.maximum(blk, lo, out=blk)
+                np.minimum(blk, hi, out=blk)
+                out[i, r0:r1] = blk
+        return out
 
     def tile_mac(self, h, t, taps, quantized=False):
         from repro.core.simulator import gemm_rows

@@ -97,8 +97,9 @@ from repro.core.schedule import (
     compile_conv_strips,
 )
 from repro.core.simulator import BlockSimulator, SimCounters, simulate_fc
-from repro.core.trace import TracePlan, TraceExecutor, compile_trace
-from repro.telemetry.spans import count, span
+from repro.core.trace import (
+    TracePlan, TraceExecutor, compile_trace, scratch_buf)
+from repro.telemetry.spans import span
 from repro.core.transport import (
     OFM,
     RESIDUAL,
@@ -416,6 +417,8 @@ class NetworkSimulator:
         # stateless and reused across runs (keeps jitted fns warm too)
         self._trace_plans: Dict[Tuple[int, int], TracePlan] = {}
         self._executors: Dict[Tuple[int, int], TraceExecutor] = {}
+        # zero-bordered padded inputs of width-tiled layers, reused
+        self._strip_pads: Dict[int, np.ndarray] = {}
         if backend == "trace":
             with span(f"trace_lower:{cnn.name}",
                       layers=len(self.schedules) + len(self._strips)):
@@ -547,9 +550,9 @@ class NetworkSimulator:
         layer = self.cnn.layers[li]
         b, p = x.shape[0], layer.p
         with span("net.pad", cat="network", layer=layer.name):
-            padded = np.zeros(
+            padded = scratch_buf(
+                self._strip_pads, li,
                 (b, layer.h + 2 * p, layer.w + 2 * p, layer.c), np.float64)
-            count("scratch_alloc_bytes", padded.nbytes)
             padded[:, p:p + layer.h, p:p + layer.w] = x
         outs = [
             self._executor(li, si, strip.sched, transport, counters)

@@ -77,6 +77,31 @@ from repro.core.transport import CHAIN, GROUP, PSUM_BYTES, NoCTransport
 from repro.telemetry.spans import count, count_device_call, span
 
 
+#: per-buffer cap on cross-run scratch retention — larger buffers stay
+#: transient so a parked simulator does not pin hundreds of MB between
+#: calls (every int8 raster of resnet50 at 16 frames fits)
+_SCRATCH_CAP_BYTES = 32 << 20
+
+
+def scratch_buf(store: dict, key, shape: Tuple[int, ...],
+                dtype) -> np.ndarray:
+    """A zero-initialized scratch array kept in ``store`` across runs.
+
+    Safe because every caller fully overwrites the elements it later
+    reads back variable data from, and the zero pad (the raster border,
+    the short-``kc`` gather tail) is never written — so the zeros from
+    the first allocation persist bit-exactly."""
+    buf = store.get(key)
+    if buf is not None and buf.shape == shape \
+            and buf.dtype == np.dtype(dtype):
+        return buf
+    buf = np.zeros(shape, dtype)
+    count("scratch_alloc_bytes", buf.nbytes)
+    if buf.nbytes <= _SCRATCH_CAP_BYTES:
+        store[key] = buf
+    return buf
+
+
 @dataclass(frozen=True)
 class TileTrace:
     """One tile's vectorized execution record, lowered from its table."""
@@ -209,29 +234,6 @@ class TraceExecutor:
 
     # -- execution -----------------------------------------------------------
 
-    #: per-buffer cap on cross-run scratch retention (f64 elements) —
-    #: larger buffers (ImageNet head layers) stay transient so a parked
-    #: simulator does not pin hundreds of MB between calls
-    _SCRATCH_CAP_ELEMS = 1 << 22
-
-    def _scratch_buf(self, key: str, shape: Tuple[int, ...],
-                     dtype) -> np.ndarray:
-        """A zero-initialized scratch array reused across runs.
-
-        Safe because every caller fully overwrites the elements it later
-        reads back variable data from, and the zero pad (the raster
-        border, the short-``kc`` gather tail) is never written — so the
-        zeros from the first allocation persist bit-exactly."""
-        buf = self._scratch.get(key)
-        if buf is not None and buf.shape == shape \
-                and buf.dtype == np.dtype(dtype):
-            return buf
-        buf = np.zeros(shape, dtype)
-        count("scratch_alloc_bytes", buf.nbytes)
-        if buf.size <= self._SCRATCH_CAP_ELEMS:
-            self._scratch[key] = buf
-        return buf
-
     def run(self, ifm: np.ndarray, account: bool = True) -> np.ndarray:
         """ifm: (H, W, C) or (B, H, W, C) -> OFM (..., E, F, M); bitwise
         identical to ``BlockSimulator.run`` on the same schedule.
@@ -248,18 +250,17 @@ class TraceExecutor:
         assert ifm.shape[1:] == (s.h, s.w, s.c_in), ifm.shape
         if self.use_jax and self.engine.name == "exact":
             out = self._run_jax(ifm)
+        elif self.fused:
+            qs8 = self._stage_quant(ifm)
+            out = self._run_jax_quant(qs8) if self.use_jax \
+                else self._execute_quant(qs8)
         else:
             with span("te.pad", cat="trace", layer=s.layer_name):
-                padded = self._scratch_buf(
-                    "padded", (b, s.hp, s.wp, s.c_in), np.float64)
+                padded = scratch_buf(
+                    self._scratch, "padded", (b, s.hp, s.wp, s.c_in),
+                    np.float64)
                 padded[:, s.pad:s.pad + s.h, s.pad:s.pad + s.w] = ifm
-            stream = padded.reshape(b, -1, s.c_in)
-            if not self.fused:
-                out = self._execute_np(stream)
-            elif self.use_jax:
-                out = self._run_jax_quant(stream)
-            else:
-                out = self._execute_quant(stream)
+            out = self._execute_np(padded.reshape(b, -1, s.c_in))
         if account:
             self._account()
         return out[0] if squeeze else out
@@ -336,27 +337,46 @@ class TraceExecutor:
         chunk = max(1, min(ef, self._QCHUNK_ELEMS // width))
         return [(lo, min(ef, lo + chunk)) for lo in range(0, ef, chunk)]
 
-    def _execute_quant(self, stream: np.ndarray) -> np.ndarray:
-        """The fused integer-native path: one stacked gather, one
-        batch-of-tiles engine MAC (batched exact integer gemm + ONE
-        vectorized ADC conversion across all T subarrays), and the
-        chain/group fold collapsed to a single code sum over tiles.
-        Bitwise-equal to ``_execute_np``'s per-tile fold: ADC codes are
-        integers exact in f64, so association order is free."""
+    def _stage_quant(self, ifm: np.ndarray) -> np.ndarray:
+        """The fused paths' input: ``ifm`` quantized straight into a
+        reused int8 padded raster, returned as the (B, Hp*Wp, C) stream
+        — the bits of ``quant_stream`` on the float64 padded copy, cast
+        to int8.  The border is never written: a zero pixel quantizes to
+        code 0, so the first allocation's zeros stay right.  Int8 codes
+        move 8x fewer bytes through the gathers and to the device."""
+        s = self.sched
+        b = ifm.shape[0]
+        with span("te.pad", cat="trace", layer=s.layer_name):
+            raster = scratch_buf(
+                self._scratch, "raster8", (b, s.hp, s.wp, s.c_in), np.int8)
+        with span("te.quant", cat="trace", layer=s.layer_name):
+            self.engine.quant_stream(
+                self.handle, ifm,
+                out=raster[:, s.pad:s.pad + s.h, s.pad:s.pad + s.w])
+        return raster.reshape(b, -1, s.c_in)
+
+    def _execute_quant(self, qs: np.ndarray) -> np.ndarray:
+        """The fused integer-native path on the int8 stream ``qs``: one
+        stacked gather, one batch-of-tiles engine MAC (batched exact
+        integer gemm + ONE vectorized ADC conversion across all T
+        subarrays), and the chain/group fold collapsed to a single code
+        sum over tiles.  Bitwise-equal to ``_execute_np``'s per-tile
+        fold: ADC codes are integers exact in f64, so association order
+        is free."""
         s = self.sched
         engine, handle = self.engine, self.handle
-        # quantized codes are int8-ranged by construction — the compact
-        # view moves 8x fewer bytes through the gathers
-        with span("te.quant", cat="trace", layer=s.layer_name):
-            qs = engine.quant_stream(handle, stream).astype(np.int8)
         b, ef, m = qs.shape[0], self.plan.fires, s.c_out
         out = np.empty((b, ef, m), np.float64)
         kcm = max(self.handle.kc)
-        for lo, hi in self._quant_chunks(ef, b):
+        chunks = self._quant_chunks(ef, b)
+        # one buffer sized for the first (widest) chunk; a shorter last
+        # chunk gathers into its leading rows
+        rows = b * (chunks[0][1] - chunks[0][0])
+        for lo, hi in chunks:
             with span("te.gather", cat="trace", layer=s.layer_name):
-                buf = self._scratch_buf(
-                    "qbuf", (len(self.plan.tiles), b * (hi - lo), kcm),
-                    self.handle.w_stack.dtype)
+                buf = scratch_buf(
+                    self._scratch, "qbuf", (len(self.plan.tiles), rows, kcm),
+                    self.handle.w_stack.dtype)[:, :b * (hi - lo)]
                 buf = self._gather_tiles(qs, lo, hi, buf)
             with span("te.mac", cat="trace", layer=s.layer_name):
                 codes = engine.tiles_mac(handle, buf)  # (B*rows, M) sums
@@ -365,19 +385,16 @@ class TraceExecutor:
 
     # -- quantized jax fast path (bitwise, unlike the exact f32 one) ---------
 
-    def _run_jax_quant(self, stream: np.ndarray) -> np.ndarray:
-        """jit flavor of the fused path: int8 gathers + the engine's jit
-        MAC (:meth:`CIMEngine.tiles_mac_fn` — one batched
-        ``lax.dot_general(..., preferred_element_type=int32)`` and the
-        shared f32 ADC conversion on the CIM engine, the Pallas kernel on
-        the Pallas engine) + the exact integer code sum.  Every op is
-        exact-integer or the shared elementwise conversion, so this path
-        is *bitwise* equal to the numpy fused/per-tile paths (codes are
-        < 2^24, exact in f32)."""
+    def _run_jax_quant(self, qs8: np.ndarray) -> np.ndarray:
+        """jit flavor of the fused path on the int8 stream ``qs8``: int8
+        gathers + the engine's jit MAC (:meth:`CIMEngine.tiles_mac_fn` —
+        one batched ``lax.dot_general(..., preferred_element_type=int32)``
+        and the shared f32 ADC conversion on the CIM engine, the Pallas
+        kernel on the Pallas engine) + the exact integer code sum.  Every
+        op is exact-integer or the shared elementwise conversion, so this
+        path is *bitwise* equal to the numpy fused/per-tile paths (codes
+        are < 2^24, exact in f32)."""
         s = self.sched
-        with span("te.quant", cat="trace", layer=s.layer_name):
-            qs8 = self.engine.quant_stream(self.handle, stream).astype(
-                np.int8)
         if self._jax_fn is None:
             with span(f"jit_build:{self.sched.layer_name}", cat="jit"):
                 self._jax_fn = self._build_jax_qfn()
@@ -388,7 +405,7 @@ class TraceExecutor:
         with span("te.fetch", cat="trace", layer=s.layer_name):
             out = np.asarray(csum, np.float64)
         count_device_call((qs8, *self._jax_fn.args[0]), csum)
-        b = stream.shape[0]
+        b = qs8.shape[0]
         return self._tail_np(out.reshape(b, s.e, s.f, s.c_out))
 
     def _build_jax_qfn(self):

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 from conftest import int_params as _int_params
 
-from repro.configs.cnn import CNN_BENCHMARKS
+from repro.configs.cnn import CNN_BENCHMARKS, ConvLayer
 from repro.core.cim import CIMSpec, adc_convert
 from repro.core.engine import CIMEngine, PallasEngine, conv_tile_slices
 from repro.core.network import NetworkSimulator
@@ -188,3 +188,127 @@ def test_network_ragged_interp_trace_stream_bitwise(engine):
     assert interp.logits.tobytes() == trace.logits.tobytes()
     assert interp.logits.tobytes() == stream.logits.tobytes()
     assert interp.logits.tobytes() == jit.logits.tobytes()
+
+
+# -- staging: the layer input quantized straight into the int8 raster ------
+
+#: every distinct (H, W, C, pad) conv input of ``CNN_BENCHMARKS`` — pads 0,
+#: 1 and 3 (the resnet50 stem), CIFAR to ImageNet widths
+STAGE_GEOMS = sorted({(l.h, l.w, l.c, l.p)
+                      for fn in CNN_BENCHMARKS.values()
+                      for l in fn().layers if isinstance(l, ConvLayer)})
+
+
+def _qhandle(a_scale):
+    """A quantized engine handle carrying ``a_scale`` (what
+    ``quant_stream`` reads: the scale and the code saturation)."""
+    eng = CIMEngine(LOSSY).set_layer("stage", a_scale=a_scale)
+    return eng, eng.fc_handle("stage", np.ones((4, 4)))
+
+
+def _staged(eng, h, x, p):
+    """The executor's staging of ``x`` into a fresh zero int8 raster."""
+    b, hh, ww, c = x.shape
+    raster = np.zeros((b, hh + 2 * p, ww + 2 * p, c), np.int8)
+    eng.quant_stream(h, x, out=raster[:, p:p + hh, p:p + ww])
+    return raster
+
+
+def _reference(eng, h, x, p):
+    """What the executor staged before: quantize the float64 padded copy,
+    then cast to int8."""
+    b, hh, ww, c = x.shape
+    padded = np.zeros((b, hh + 2 * p, ww + 2 * p, c), np.float64)
+    padded[:, p:p + hh, p:p + ww] = x
+    return eng.quant_stream(h, padded).astype(np.int8)
+
+
+@pytest.mark.parametrize("geom", STAGE_GEOMS, ids=lambda g: "x".join(
+    map(str, g[:3])) + f"p{g[3]}")
+def test_stage_quant_bitwise_on_benchmark_geometries(geom):
+    """The blocked pass into the raster interior equals the float64
+    padded quantization cast to int8, border included, on every
+    benchmark conv input (the scale saturates the tails at both clip
+    edges)."""
+    hh, ww, c, p = geom
+    x = np.random.default_rng(hh * ww + c + p).standard_normal(
+        (2, hh, ww, c))
+    eng, h = _qhandle(float(np.abs(x).max()) / 200)
+    got = _staged(eng, h, x, p)
+    assert got.tobytes() == _reference(eng, h, x, p).tobytes()
+    assert got.min() == -128 and got.max() == 127
+
+
+def test_stage_quant_float32_divides_in_float64():
+    """A float32 input is divided in float64, as its float64 padded copy
+    was: on inputs next to the .5 ties, where a float32 division would
+    round the other way, the staged codes equal the float64 reference."""
+    a = 0.0137
+    ties = (np.arange(-130, 130) + 0.5) * a
+    x32 = np.concatenate([
+        np.nextafter(np.float32(ties), np.float32(d))
+        for d in (-np.inf, 0, np.inf)]).astype(np.float32)
+    x32 = np.concatenate([np.float32(ties), x32])
+    # the data discriminate: a float32 division rounds some differently
+    f64 = np.rint(x32.astype(np.float64) / a)
+    assert (np.rint(x32 / np.float32(a)) != f64).any()
+    x = np.resize(x32, (2, 5, 7, 16))
+    eng, h = _qhandle(a)
+    got = _staged(eng, h, x, 1)
+    assert got.tobytes() == _reference(eng, h, x, 1).tobytes()
+
+
+def test_stage_quant_strided_width_strip_view():
+    """A non-contiguous width strip of a padded input (what
+    ``NetworkSimulator._run_layer`` hands each strip executor) stages to
+    the same codes as its contiguous copy."""
+    r = np.random.default_rng(5)
+    padded = r.standard_normal((3, 20, 230, 6))
+    eng, h = _qhandle(0.01)
+    for lo, hi in ((0, 100), (96, 230), (57, 58)):
+        view = padded[:, :, lo:hi]
+        assert not view.flags.c_contiguous
+        got = _staged(eng, h, view, 0)
+        assert got.tobytes() == _reference(
+            eng, h, np.ascontiguousarray(view), 0).tobytes()
+
+
+@pytest.mark.parametrize("a_scale", [0.25, 0.0137])
+def test_stage_quant_ties_and_clip_edges(a_scale):
+    """Exact .5 ties of ``x / a_scale`` round half to even and the codes
+    saturate at -128 and 127, as ``np.clip(np.round(...))`` does."""
+    k = np.arange(-140, 141, dtype=np.float64)
+    vals = np.concatenate([
+        (k + 0.5) * a_scale, k * a_scale,
+        np.array([-128.5, -128.49, -129.0, 127.5, 127.49, 128.0,
+                  1e9, -1e9, 0.0, -0.0, 1e-300, -1e-300]) * a_scale])
+    x = np.resize(vals, (2, 3, 4, vals.size // 24 + 1))
+    eng, h = _qhandle(a_scale)
+    got = _staged(eng, h, x, 2)
+    assert got.tobytes() == _reference(eng, h, x, 2).tobytes()
+    if a_scale == 0.25:  # power of two: the quotients are exact ties
+        codes = got[:, 2:-2, 2:-2].ravel()[:k.size]
+        want = np.clip(np.round(k + 0.5), -128, 127)
+        assert (codes == want).all()
+        assert {-128, 127} <= set(codes.tolist())
+
+
+@pytest.mark.parametrize("engine", list(ENGINES))
+@pytest.mark.parametrize("use_jax", [False, True])
+def test_reused_raster_back_to_back_runs(engine, use_jax):
+    """One executor on two inputs back to back: the second output equals
+    a fresh executor's, and both runs staged into the same int8 raster —
+    its border stays zero and nothing of the first input, on the host
+    or the device, reaches the second."""
+    sched, wts, ifm, eng = _block(50, NARROW, ENGINES[engine], 2,
+                                  **GEOMS[0])
+    other = np.random.default_rng(51).standard_normal(ifm.shape) * 3
+    ex = TraceExecutor(sched, wts, engine=eng, use_jax=use_jax)
+    ex.run(other)
+    raster = ex._scratch["raster8"]
+    second = ex.run(ifm)
+    assert ex._scratch["raster8"] is raster
+    fresh = TraceExecutor(sched, wts, engine=eng, use_jax=use_jax).run(ifm)
+    assert second.tobytes() == fresh.tobytes()
+    p = sched.pad
+    assert raster.tobytes() == _reference(eng, ex.handle, ifm, p).tobytes()

@@ -33,10 +33,13 @@ import numpy as np
 import pytest
 from conftest import int_params as _int_params
 
-from repro.configs.cnn import CNN_BENCHMARKS, FCLayer
+from repro.configs.cnn import CNN_BENCHMARKS, CNNConfig, ConvLayer, FCLayer
 from repro.core.energy import routed_byte_hops_per_class
+from repro.core.engine import CIMEngine
 from repro.core.mapping import plan_network
 from repro.core.network import NetworkSimulator
+from repro.core.schedule import compile_conv_block
+from repro.core.trace import TraceExecutor
 from repro.dse.placements import strategies
 from repro.runtime.serve_loop import serve_stream
 from repro.telemetry import (MetricsRegistry, Profiler, active_profiler,
@@ -325,6 +328,55 @@ def test_hot_path_spans_nest_in_one_call_and_count_h2d():
     assert prof.counts["dispatches"] == calls
     # every scratch buffer was made in the first call
     assert prof.counts.get("scratch_alloc_bytes", 0) == 0
+
+
+@pytest.mark.parametrize("use_jax", [False, True])
+def test_scratch_retained_above_old_element_cap(use_jax):
+    """A 16-frame 56x56x256 layer input — 12.8 M elements, above the 4 M
+    float64 elements the scratch cap once kept — stages into an int8
+    raster that is kept: from the second call on nothing is allocated,
+    on the jit path and on the host path, whose gather buffer serves a
+    fire axis cut into chunks of two lengths."""
+    sched = compile_conv_block("wide", 56, 56, 256, 8, 1, 1, 0)
+    rng = np.random.default_rng(4)
+    x = rng.standard_normal((16, 56, 56, 256))
+    w = rng.standard_normal((1, 1, 256, 8))
+    eng = CIMEngine().set_layer("wide", a_scale=0.02)
+    ex = TraceExecutor(sched, w, engine=eng, use_jax=use_jax)
+    with Profiler() as first:
+        want = ex.run(x)
+    assert ex._scratch["raster8"].size > 1 << 22
+    assert first.counts["scratch_alloc_bytes"] >= x.size
+    if not use_jax:
+        lens = {hi - lo for lo, hi in ex._quant_chunks(ex.plan.fires, 16)}
+        assert len(lens) == 2
+    with Profiler() as prof:
+        got = ex.run(x)
+    assert prof.counts.get("scratch_alloc_bytes", 0) == 0
+    assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("engine", ["exact", "cim"])
+def test_strip_pad_retained_across_calls(engine):
+    """A layer wider than one instruction table runs as width strips over
+    one padded input: that pad, like each strip executor's raster, is
+    kept, so the second call allocates no scratch and reads the same."""
+    cnn = CNNConfig("wide", "cifar10", 130, (
+        ConvLayer("c1", 6, 130, 3, 4), FCLayer("fc", 6 * 130 * 4, 10)))
+    rng = np.random.default_rng(6)
+    params = _int_params(cnn, rng)
+    x = rng.standard_normal((2, 6, 130, 3))
+    kw = {} if engine == "exact" else {"engine": engine,
+                                       "calib_images": x[:1]}
+    sim = NetworkSimulator(cnn, params, backend="trace", **kw)
+    assert 0 in sim._strips
+    with Profiler() as first:
+        want = sim.run(x)
+    assert first.counts["scratch_alloc_bytes"] > 0
+    with Profiler() as prof:
+        got = sim.run(x)
+    assert prof.counts.get("scratch_alloc_bytes", 0) == 0
+    assert got.logits.tobytes() == want.logits.tobytes()
 
 
 def test_stream_timeline_trace_is_valid():
