@@ -642,18 +642,19 @@ class NetworkSimulator:
                                  counters=traffic, recorder=self.recorder)
         if stage.kind == "fc":
             assert isinstance(layer, FCLayer)
-            if x.ndim == 4:
-                if self.cnn.name.startswith("resnet"):
-                    x = x.mean(axis=(1, 2))  # global average pool
-                else:
-                    x = x.reshape(x.shape[0], -1)  # VGG flattens
             act = "relu" if li < len(self.cnn.layers) - 1 else None
-            return simulate_fc(
-                x, np.asarray(self.params[layer.name], np.float64),
-                self.n_c, self.n_m, activation=act,
-                counters=counters,
-                transport=transport if account else None,
-                engine=self.pe_engine, handle=self._handles[li])
+            with span("net.fc", cat="network", layer=layer.name):
+                if x.ndim == 4:
+                    if self.cnn.name.startswith("resnet"):
+                        x = x.mean(axis=(1, 2))  # global average pool
+                    else:
+                        x = x.reshape(x.shape[0], -1)  # VGG flattens
+                return simulate_fc(
+                    x, np.asarray(self.params[layer.name], np.float64),
+                    self.n_c, self.n_m, activation=act,
+                    counters=counters,
+                    transport=transport if account else None,
+                    engine=self.pe_engine, handle=self._handles[li])
 
         mesh_root = NoCTransport(noc, base=0, counters=traffic,
                                  recorder=self.recorder)
