@@ -61,8 +61,9 @@ from repro.telemetry.spans import count_device_call, span
 ENGINES = ("exact", "cim", "pallas")
 
 #: elements per block of the blocked activation quantization
-#: (:meth:`CIMEngine.quant_stream` with ``out``): a 256 KiB float64 block
-#: buffer that stays in the core's cache across its four passes
+#: (:meth:`CIMEngine.quant_stream` with ``out``) and of the trace
+#: executor's block tail: a 256 KiB float64 block buffer that stays in
+#: the core's cache across its passes
 _QBLOCK_ELEMS = 1 << 15
 
 
@@ -229,8 +230,14 @@ class PEEngine:
         :meth:`quant_stream` (skip per-tap quantization)."""
         raise NotImplementedError
 
-    def finalize_conv(self, h: ConvHandle, acc: np.ndarray) -> np.ndarray:
-        return acc
+    def finalize_conv(self, h: ConvHandle, acc: np.ndarray,
+                      out: Optional[np.ndarray] = None) -> np.ndarray:
+        """The identity; with ``out`` (float64, ``acc``'s shape), ``acc``
+        copied there."""
+        if out is None:
+            return acc
+        np.copyto(out, acc)
+        return out
 
     # -- fc -----------------------------------------------------------------
     def fc_handle(self, name: str, w: np.ndarray,
@@ -644,8 +651,11 @@ class CIMEngine(PEEngine):
 
         return fn, (h.w8_stack,)
 
-    def finalize_conv(self, h, acc):
-        return acc * h.deq
+    def finalize_conv(self, h, acc, out=None):
+        """Code sums times ``deq``, in float64 whatever exact-integer dtype
+        ``acc`` has; with ``out`` (float64, ``acc``'s shape) written
+        there."""
+        return np.multiply(acc, h.deq, out=out, dtype=np.float64)
 
     def fc_mac(self, h, x, k0, k1, n0, n1, quantized=False):
         xq = x if quantized else self._quant(x, h)

@@ -70,6 +70,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from repro.core.engine import _QBLOCK_ELEMS
 from repro.core.instructions import BUF_PUSH, FROM_PE, Instruction, Port
 from repro.core.schedule import BlockSchedule
 from repro.core.simulator import SimCounters, _standalone_transport
@@ -227,6 +228,7 @@ class TraceExecutor:
         self.weights: List[np.ndarray] = self.handle.tile_w
         self._psum_bytes = sched.c_out * PSUM_BYTES
         self._jax_fn = None
+        self._deq_positive = (None, False)   # (handle, all deq > 0)
         # zero-initialized work buffers reused across runs (the batched
         # streaming numerics pass calls each executor once per frame
         # chunk, so the padded raster / gather buffers are hot)
@@ -402,8 +404,10 @@ class TraceExecutor:
         # the device); the fetch then waits for it and copies back
         with span("te.step", cat="trace", layer=s.layer_name):
             csum = self._jax_fn(qs8)
+        # the step's own int32 / float32 code sums (exact integers below
+        # 2^24): the tail widens them block by block
         with span("te.fetch", cat="trace", layer=s.layer_name):
-            out = np.asarray(csum, np.float64)
+            out = np.asarray(csum)
         count_device_call((qs8, *self._jax_fn.args[0]), csum)
         b = qs8.shape[0]
         return self._tail_np(out.reshape(b, s.e, s.f, s.c_out))
@@ -435,33 +439,94 @@ class TraceExecutor:
 
         return functools.partial(jax.jit(fn), operands)
 
-    def _tail_np(self, out: np.ndarray) -> np.ndarray:
+    def _pool_first(self) -> bool:
+        """Whether the block tail may max-pool the code sums before
+        dequantizing, checked once per handle (a variation swap replaces
+        it): every ``deq`` is positive.  Multiplying by a positive float64,
+        adding the bias and the ReLU are then monotone non-decreasing per
+        channel, so they commute with ``max`` bit for bit — and no -0.0
+        can tie with a +0.0, because a zero code is +0.0.  The exact
+        engine's float64 outputs carry no ``deq`` and keep the order."""
+        h = self.handle
+        if self._deq_positive[0] is not h:
+            self._deq_positive = (
+                h, h.deq is not None and bool(np.all(h.deq > 0)))
+        return self._deq_positive[1]
+
+    def _tail_np(self, acc: np.ndarray) -> np.ndarray:
         """Block-tail M-type program: dequantization (quantized engines),
         bias, activation, Fig. 9 pooling — each fold replayed in the
-        interpreter's operand order."""
+        interpreter's operand order.
+
+        ``acc`` (B, E, F, M): exact integer code sums in the dtype the MAC
+        returned (int32 or float32 from the device, float64 from the host
+        paths), or the exact engine's float64 outputs.  One pass over
+        blocks of about ``_QBLOCK_ELEMS`` input elements: each block is
+        pooled, dequantized, biased and ReLU'd in reused buffers and
+        written straight into the output, which is allocated afresh every
+        run — a stage output may be kept as a later stage's residual
+        input.  With :meth:`_pool_first` the pool runs on the code sums
+        first, so a 2x2-pooled layer dequantizes a quarter of them;
+        otherwise the pool comes last, as the interpreter orders it."""
         s = self.sched
         with span("te.tail", cat="trace", layer=s.layer_name):
-            b = out.shape[0]
-            out = self.engine.finalize_conv(self.handle, out)
-            if self.bias is not None:
-                out = out + self.bias
-            if s.tail.activation == "relu":
-                out = np.maximum(out, 0.0)
-            ps = s.tail.pool_s
-            if ps:
-                assert s.e % ps == 0 and s.f % ps == 0, (
-                    f"pooling {ps} does not tile the {s.e}x{s.f} OFM")
-                win = out.reshape(b, s.e // ps, ps, s.f // ps, ps, s.c_out)
-                # running row max in y order (POOL_STORE then POOL_MAX ...)
-                row = win[:, :, :, :, 0]
-                for y in range(1, ps):
-                    row = np.maximum(row, win[:, :, :, :, y])
-                # fold window rows in x order (row buffer merge, POOL_OUT)
-                res = row[:, :, 0]
-                for x in range(1, ps):
-                    res = np.maximum(res, row[:, :, x])
-                out = res
+            b, e, f, m = acc.shape
+            ps = s.tail.pool_s or 1
+            assert e % ps == 0 and f % ps == 0, (
+                f"pooling {ps} does not tile the {e}x{f} OFM")
+            first = ps > 1 and self._pool_first()
+            out = np.empty((b, e // ps, f // ps, m), np.float64)
+            # pool windows never straddle frames: walk frames x rows
+            src = acc.reshape(b * e, f, m)
+            dst = out.reshape(b * e // ps, f // ps, m)
+            step = max(1, _QBLOCK_ELEMS // (ps * f * m))  # out rows a block
+            n = min(len(dst), step)
+            if ps > 1:
+                pdt = acc.dtype if first else np.dtype(np.float64)
+                row = scratch_buf(self._scratch, ("tail_row", pdt.str),
+                                  (n, ps, f // ps, m), pdt)
+                buf = scratch_buf(self._scratch, ("tail_pre", pdt.str),
+                                  (n, f // ps, m) if first else
+                                  (n * ps, f, m), pdt)
+            for r0 in range(0, len(dst), step):
+                k = min(len(dst), r0 + step) - r0
+                blk, res = src[r0 * ps:(r0 + k) * ps], dst[r0:r0 + k]
+                if first:
+                    self._pool(blk, ps, row[:k], buf[:k])
+                    self._finalize(buf[:k], res)
+                elif ps > 1:
+                    self._finalize(blk, buf[:k * ps])
+                    self._pool(buf[:k * ps], ps, row[:k], res)
+                else:
+                    self._finalize(blk, res)
+            if first:
+                count("te.tail_pool_first", acc.size)
             return out
+
+    def _finalize(self, acc: np.ndarray, out: np.ndarray) -> None:
+        """Dequantization, bias and activation of one block, into the
+        float64 ``out`` and in place there."""
+        self.engine.finalize_conv(self.handle, acc, out=out)
+        if self.bias is not None:
+            np.add(out, self.bias, out=out)
+        if self.sched.tail.activation == "relu":
+            np.maximum(out, 0.0, out=out)
+
+    @staticmethod
+    def _pool(blk: np.ndarray, ps: int, row: np.ndarray,
+              out: np.ndarray) -> None:
+        """Fig. 9 max fold of ``blk`` (R*ps, F, M) into ``out``
+        (R, F/ps, M): the running max over each window row's pixels in x
+        order (POOL_STORE then POOL_MAX ...) into ``row``
+        (R, ps, F/ps, M), then the window rows folded in y order (row
+        buffer merge, POOL_OUT)."""
+        win = blk.reshape(len(out), ps, out.shape[1], ps, out.shape[2])
+        np.maximum(win[:, :, :, 0], win[:, :, :, 1], out=row)
+        for x in range(2, ps):
+            np.maximum(row, win[:, :, :, x], out=row)
+        np.maximum(row[:, 0], row[:, 1], out=out)
+        for y in range(2, ps):
+            np.maximum(out, row[:, y], out=out)
 
     # -- jax fast path (behind the flag; float32, approximate) ---------------
 

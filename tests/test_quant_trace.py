@@ -4,18 +4,26 @@ fold's ADC codes bit-for-bit on ragged geometries — K % n_c != 0,
 C > N_c split chains, FC grids whose tile spans several spec subarrays,
 B == 1 — for both quantized engines and for the jit flavor, and the
 vectorized conversion must equal per-tile conversion code-for-code."""
+import dataclasses
+
 import numpy as np
 import pytest
 from conftest import int_params as _int_params
 
-from repro.configs.cnn import CNN_BENCHMARKS, ConvLayer
+from repro.configs.cnn import CNN_BENCHMARKS, ConvLayer, _vgg
 from repro.core.cim import CIMSpec, adc_convert
-from repro.core.engine import CIMEngine, PallasEngine, conv_tile_slices
+from repro.core.engine import (
+    EXACT_ENGINE,
+    CIMEngine,
+    PallasEngine,
+    conv_tile_slices,
+)
 from repro.core.network import NetworkSimulator
 from repro.core.schedule import compile_conv_block
 from repro.core.simulator import BlockSimulator, simulate_fc
 from repro.core.trace import TraceExecutor
 from repro.core.variation import VariationModel
+from repro.telemetry.spans import Profiler
 
 LOSSY = CIMSpec(n_c=256, adc_bits=8, gain=64.0)
 #: small subarray so conv tiles are K-ragged (kc < n_c) *and* FC grid
@@ -312,3 +320,188 @@ def test_reused_raster_back_to_back_runs(engine, use_jax):
     assert second.tobytes() == fresh.tobytes()
     p = sched.pad
     assert raster.tobytes() == _reference(eng, ex.handle, ifm, p).tobytes()
+
+
+# -- the block tail: one blocked pass over the code sums -------------------
+
+#: every distinct conv output (E, F, M) of ``CNN_BENCHMARKS`` before any
+#: pool, each with and without a 2x2/s2 pool where the pool tiles it
+TAIL_GEOMS = sorted({((l.h + 2 * l.p - l.k + l.s) // l.s,
+                      (l.w + 2 * l.p - l.k + l.s) // l.s, l.m)
+                     for fn in CNN_BENCHMARKS.values()
+                     for l in fn().layers if isinstance(l, ConvLayer)})
+TAIL_CASES = [(e, f, m, ps) for e, f, m in TAIL_GEOMS for ps in (0, 2)
+              if e % 2 == 0 and f % 2 == 0 or not ps]
+
+#: how the code sums reach the tail: the jit path's int32 (CIM) or
+#: float32 (Pallas) sums, the host paths' float64, and an int32 width
+#: strip of a wider array (non-contiguous)
+TAIL_LAYOUTS = ("int32", "float32", "float64", "int32-strip")
+
+
+def _tail_executor(m, pool_s, engine=None, bias=None):
+    """An executor whose block tail has ``m`` output channels, ReLU and a
+    ``pool_s`` pool; the tail takes its E and F from the code sums."""
+    hw = max(pool_s, 1)
+    sched = compile_conv_block(f"tail{m}p{pool_s}", hw, hw, 4, m, 1, 1, 0,
+                               pool_k=pool_s, pool_s=pool_s)
+    wts = np.random.default_rng(m).standard_normal((1, 1, 4, m))
+    if engine is None:
+        engine = CIMEngine(LOSSY).set_layer(sched.layer_name, a_scale=0.01)
+    return TraceExecutor(sched, wts, engine=engine, bias=bias)
+
+
+def _code_sums(shape, h, seed):
+    """Exact integer code sums of three tiles' codes, a quarter exact
+    zeros, with the single-tile and three-tile saturation edges."""
+    r = np.random.default_rng(seed)
+    lo, hi = 3 * h.code_lo, 3 * h.code_hi
+    codes = r.integers(lo, hi + 1, shape).astype(np.float64)
+    pick = r.random(shape)
+    codes[pick < 0.25] = 0.0
+    for v, (a, b) in zip((lo, hi, h.code_lo, h.code_hi),
+                         ((0.25, 0.27), (0.27, 0.29), (0.29, 0.31),
+                          (0.31, 0.33))):
+        codes[(pick >= a) & (pick < b)] = v
+    return codes
+
+
+def _layout(codes, layout):
+    if layout == "int32-strip":
+        b, e, f, m = codes.shape
+        wide = np.zeros((b, e, f + 5, m), np.int32)
+        view = wide[:, :, 3:3 + f]
+        view[...] = codes
+        assert not view.flags.c_contiguous
+        return view
+    return codes.astype(layout)
+
+
+def _stepwise_tail(acc, deq, bias, pool_s):
+    """The tail as separate full-size steps: widen, times ``deq``, bias,
+    ReLU, then the Fig. 9 fold — the running max over each window row's
+    pixels, then over the window rows."""
+    out = np.asarray(acc, np.float64)
+    if deq is not None:
+        out = out * deq
+    if bias is not None:
+        out = out + bias
+    out = np.maximum(out, 0.0)
+    if pool_s:
+        b, e, f, m = out.shape
+        win = out.reshape(b, e // pool_s, pool_s, f // pool_s, pool_s, m)
+        row = win[:, :, :, :, 0]
+        for x in range(1, pool_s):
+            row = np.maximum(row, win[:, :, :, :, x])
+        out = row[:, :, 0]
+        for y in range(1, pool_s):
+            out = np.maximum(out, row[:, :, y])
+    return out
+
+
+def _tail_pool_first(ex, acc):
+    """The tail's output and its ``te.tail_pool_first`` count."""
+    with Profiler() as prof:
+        got = ex._tail_np(acc)
+    return got, prof.counts.get("te.tail_pool_first", 0)
+
+
+@pytest.mark.parametrize("layout", TAIL_LAYOUTS)
+@pytest.mark.parametrize("case", TAIL_CASES, ids=lambda c: "x".join(
+    map(str, c[:3])) + f"p{c[3]}")
+def test_tail_bitwise_on_benchmark_geometries(case, layout):
+    """The blocked tail equals the stepwise tail byte for byte on every
+    benchmark conv output, pooled before dequantization where it pools,
+    whatever dtype and strides the code sums come in."""
+    e, f, m, ps = case
+    ex = _tail_executor(m, ps)
+    codes = _code_sums((2, e, f, m), ex.handle, e * f + m + ps)
+    acc = _layout(codes, layout)
+    got, pooled = _tail_pool_first(ex, acc)
+    want = _stepwise_tail(codes, ex.handle.deq, None, ps)
+    assert got.dtype == np.float64 and got.flags.c_contiguous
+    assert got.tobytes() == want.tobytes()
+    assert pooled == (codes.size if ps else 0)
+    assert (got == 0).any() and (got > 0).any()
+    again = ex._tail_np(acc)
+    assert again is not got and again.tobytes() == got.tobytes()
+
+
+@pytest.mark.parametrize("bad", [0.0, -1e-3])
+def test_tail_fallback_order_without_positive_deq(bad):
+    """A handle with one channel's ``deq`` not positive keeps the
+    dequantize-then-pool order and still matches the stepwise tail
+    bitwise; the pool-first counter skips it, and it is decided anew
+    when the executor's handle is replaced."""
+    ex = _tail_executor(16, 2)
+    codes = _code_sums((2, 8, 6, 16), ex.handle, 3)
+    _, pooled = _tail_pool_first(ex, codes.astype(np.int32))
+    assert pooled == codes.size
+    deq = ex.handle.deq.copy()
+    deq[5] = bad
+    ex.handle = dataclasses.replace(ex.handle, deq=deq)
+    for dt in (np.int32, np.float32, np.float64):
+        got, pooled = _tail_pool_first(ex, codes.astype(dt))
+        assert got.tobytes() == _stepwise_tail(
+            codes, deq, None, 2).tobytes()
+        assert pooled == 0
+
+
+def test_tail_exact_engine_with_bias_keeps_order():
+    """The exact engine's float64 outputs, signed zeros among them, take
+    bias, ReLU, then the pool, as the stepwise tail does; nothing is
+    counted as pooled first."""
+    r = np.random.default_rng(8)
+    bias = r.standard_normal(12)
+    ex = _tail_executor(12, 2, engine=EXACT_ENGINE, bias=bias)
+    acc = r.standard_normal((3, 6, 10, 12))
+    acc[r.random(acc.shape) < 0.2] = -0.0
+    acc[..., 0] = -bias[0]
+    got, pooled = _tail_pool_first(ex, acc)
+    assert got.tobytes() == _stepwise_tail(acc, None, bias, 2).tobytes()
+    assert pooled == 0
+
+
+#: ``bench/tests/test_bench_vgg16.py``'s narrow VGG-16: the published
+#: plan at a sixteenth of its widths, 128x128 frames (two strip layers,
+#: one of them pooled; five 2x2 pools)
+VGG16_NARROW = [4, 4, "M", 8, 8, "M", 16, 16, 16, "M",
+                32, 32, 32, "M", 32, 32, 32, "M"]
+
+
+def test_narrow_vgg16_jit_serving_pools_first_and_matches_pertile(
+        monkeypatch):
+    """On the narrow VGG-16, jit serving on the Pallas engine counts every
+    pooled layer's pre-pool elements as pooled first, and its logits
+    equal the per-tile ``fused=False`` path's bitwise."""
+    from repro.runtime.serve_loop import build_stream_sim, serve_stream
+
+    cnn = _vgg("vgg16-narrow", VGG16_NARROW, "imagenet", 128,
+               (256, 256, 10))
+    r = np.random.default_rng(16)
+    params = _int_params(cnn, r)
+    calib = r.standard_normal((2, 128, 128, 3))
+    x = r.standard_normal((2, 128, 128, 3))
+
+    def serve(engine, **kw):
+        sim = build_stream_sim(cnn, params, engine=engine,
+                               calib_images=calib, **kw)
+        with Profiler() as prof:
+            rep = serve_stream(sim, x, batch_window=2)
+        return rep.logits, prof.counts.get("te.tail_pool_first", 0)
+
+    jit, pooled = serve("pallas", trace_jit=True)
+    pre_pool = sum(
+        len(x) * (l.h + 2 * l.p - l.k + l.s) // l.s
+        * ((l.w + 2 * l.p - l.k + l.s) // l.s) * l.m
+        for l in cnn.conv_layers if l.pool_s)
+    assert sum(1 for l in cnn.conv_layers if l.pool_s) == 5
+    assert pooled == pre_pool
+
+    init = TraceExecutor.__init__
+    monkeypatch.setattr(
+        TraceExecutor, "__init__",
+        lambda self, *a, **kw: init(self, *a, fused=False, **kw))
+    pertile, _ = serve("cim")
+    assert np.abs(jit).max() > 0
+    assert jit.tobytes() == pertile.tobytes()
