@@ -3,8 +3,8 @@
 The simulator compiles one program per (layer, strip) on its jitted
 trace path and one Pallas kernel per distinct chunk shape, so a cold
 ImageNet run compiles a few hundred small programs; the cache keeps
-them across runs.  Entry points (``chip_smoke.py``,
-``benchmarks/run.py``, the ``examples/`` scripts) call
+them across runs.  Entry points (``bench/cell.py``,
+``chip_smoke.py``, ``examples/cnn_inference.py``) call
 :func:`enable_compile_cache`; importing the package never does, so the
 tests run without a persistent cache.
 """

@@ -6,7 +6,8 @@ without printing them): the per-byte-per-hop link energy and the per-byte
 buffer access energy; both are documented below and cross-checked against
 Tab. 4's "on-chip data moving" / "on-chip memory" columns for VGG-16/19.
 
-Anchors reproduced *exactly* by construction (validated in benchmarks):
+Anchors reproduced *exactly* by construction (validated in
+``tests/test_mapping_energy.py``):
 
 * CIM energy      = MACs x 48.1 fJ           (Tab. 4: VGG-16 744.1 uJ,
                                               VGG-19 944.3 uJ — exact)

@@ -19,11 +19,10 @@ Two execution backends share the placement, schedules and transport:
   (``core/trace.py``): each block's schedule is lowered once to
   gather/gemm form and executed as a handful of batched ops, bitwise-
   equal to the interpreter (``tests/test_trace.py``).  It removes the
-  cycle loop entirely; what remains is the conv arithmetic, so the
-  measured gain is gemm-bound (3.5x on the 2-core CI box, more on
-  wider machines — see README "Simulator backends").  ``trace_jit=True``
-  additionally routes the math through ``jax.jit`` (float32, allclose
-  not bitwise; 8.9x at serving batch sizes on the same box).
+  cycle loop entirely; what remains is the conv arithmetic.  On the
+  quantized engines ``trace_jit=True`` additionally routes each layer's
+  integer MAC through a ``jax.jit`` step, bitwise-equal to the numpy
+  trace.
 
 Batching: the IFM batch rides each routed packet as ``(B, C)`` lanes, so
 one simulated pass serves a whole batch (see ``core/simulator.py``).
@@ -88,7 +87,7 @@ from repro.core.engine import (
     make_engine,
 )
 from repro.core.instructions import TABLE_CAPACITY
-from repro.core.mapping import NetworkPlan, plan_network
+from repro.core.mapping import LayerPlan, NetworkPlan, plan_network
 from repro.core.noc import Placement, block_spans, place_network
 from repro.core.schedule import (
     BlockSchedule,
@@ -193,6 +192,23 @@ def _is_shortcut(layer) -> bool:
     return isinstance(layer, ConvLayer) and layer.name.endswith("_sc")
 
 
+def compile_conv_layer(layer: ConvLayer, lp: LayerPlan
+                       ) -> BlockSchedule | Tuple[ConvStrip, ...]:
+    """One conv layer's schedule under its plan ``lp``: a single
+    block, or width strips run back to back on the same tile chain when
+    the period W + 2P exceeds the 128-entry table.  Residual targets and
+    projection shortcuts compile with a bare tail: their activation fires
+    *after* the shortcut add."""
+    act = None if (layer.residual_from or _is_shortcut(layer)) else "relu"
+    kw = dict(h=layer.h, w=layer.w, c_in=layer.c, c_out=layer.m,
+              k=layer.k, stride=layer.s, pad=layer.p, pack=lp.pack,
+              c_splits=lp.c_splits, pool_k=layer.pool_k,
+              pool_s=layer.pool_s, activation=act)
+    if layer.w + 2 * layer.p > TABLE_CAPACITY:
+        return compile_conv_strips(layer.name, **kw)
+    return compile_conv_block(layer.name, **kw)
+
+
 #: default numerics micro-batch for the batched streaming path: frames
 #: per stage-major sweep (bounds the working set; chunk boundaries
 #: cannot change a bit — see ``run_stream``)
@@ -289,9 +305,9 @@ class NetworkSimulator:
         integer-native lowering (one batch-of-tiles gemm + one
         vectorized ADC conversion per layer chunk — see
         ``core/trace.py``), ADC-code-bitwise with the interpreter;
-        ``trace_jit=True`` selects their jitted flavor, which (unlike
-        the exact engine's float32 jit) is also bitwise and therefore
-        composes with ``streaming=True``.
+        ``trace_jit=True`` selects their jitted flavor, which is also
+        bitwise and therefore composes with ``streaming=True``.  The
+        exact engine has no jitted flavor.
         """
         if backend not in BACKENDS:
             raise ValueError(f"backend must be one of {BACKENDS}: {backend}")
@@ -304,13 +320,10 @@ class NetworkSimulator:
                 "streaming=True requires backend='trace' (the pipelined "
                 "executor advances compiled per-stage trace plans)")
         self.pe_engine: PEEngine = make_engine(engine, cim_spec)
-        if streaming and trace_jit and self.pe_engine.name == "exact":
+        if trace_jit and self.pe_engine.name == "exact":
             raise ValueError(
-                "streaming=True is incompatible with trace_jit=True on "
-                "the exact engine: its float32 jitted path is "
-                "allclose-only, which would break run_stream's per-frame "
-                "bitwise-vs-sequential guarantee (quantized engines' "
-                "integer jit flavor IS bitwise, so they may combine)")
+                "trace_jit=True needs a quantized engine (cim/pallas): the "
+                "exact engine's float64 numerics run on the host only")
         # residual wiring follows the configs/cnn.py naming convention the
         # jax reference uses (save at `*_a`, add at `residual_from`,
         # project through an immediately-following `*_sc`) — reject
@@ -391,28 +404,15 @@ class NetworkSimulator:
                     f"{placement.noc.cols} mesh")
         self.placement: Placement = placement
         self.schedules: List[Optional[BlockSchedule]] = []
-        # layers whose period W + 2P exceeds the 128-entry table compile
-        # as width strips run back to back on the same tile chain
+        # width-tiled layers: their strips, in column order
         self._strips: Dict[int, Tuple[ConvStrip, ...]] = {}
         for li, (layer, lp) in enumerate(zip(cnn.layers, self.plan.layers)):
+            sched = None  # FC runs the Fig. 4 grid
             if isinstance(layer, ConvLayer):
-                # residual targets and projection shortcuts compile with a
-                # bare tail: activation fires *after* the shortcut add
-                act = None if (layer.residual_from or _is_shortcut(layer)) \
-                    else "relu"
-                kw = dict(h=layer.h, w=layer.w, c_in=layer.c,
-                          c_out=layer.m, k=layer.k, stride=layer.s,
-                          pad=layer.p, pack=lp.pack, c_splits=lp.c_splits,
-                          pool_k=layer.pool_k, pool_s=layer.pool_s,
-                          activation=act)
-                if layer.w + 2 * layer.p > TABLE_CAPACITY:
-                    self._strips[li] = compile_conv_strips(layer.name, **kw)
-                    self.schedules.append(None)
-                else:
-                    self.schedules.append(
-                        compile_conv_block(layer.name, **kw))
-            else:
-                self.schedules.append(None)  # FC runs the Fig. 4 grid
+                sched = compile_conv_layer(layer, lp)
+                if isinstance(sched, tuple):
+                    self._strips[li], sched = sched, None
+            self.schedules.append(sched)
         # trace backend: lower every schedule once; executors are
         # stateless and reused across runs (keeps jitted fns warm too)
         self._trace_plans: Dict[Tuple[int, int], TracePlan] = {}
@@ -506,7 +506,6 @@ class NetworkSimulator:
         self._build_handles()
         for (li, _si), ex in self._executors.items():
             ex.handle = self._handles[li]
-            ex.weights = ex.handle.tile_w
             ex._jax_fn = None
 
     def _executor(self, li: int, si: int, sched: BlockSchedule,

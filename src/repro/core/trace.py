@@ -22,8 +22,8 @@ batched gather/gemm ops, bitwise-equal to the interpreter:
 
 * :class:`TraceExecutor` runs the plan: per tile one gather + ``pack``
   gemms, then the segment fold in exact interpreter order (own MAC +
-  west psum, chain total + north group-sum), tail bias/activation/pool
-  — numpy by default, ``jax.jit`` behind the ``use_jax`` flag.
+  west psum, chain total + north group-sum), tail bias/activation/pool,
+  in numpy.
 
 Quantized engines (``engine="cim"``/``"pallas"``) take a **fused
 integer-native lowering** of the same plan instead of the per-tile
@@ -37,11 +37,10 @@ code sum over the tile axis.  This is bitwise-equal to the per-tile
 fold *by construction*: ADC codes are small integers exact in float64,
 so association order cannot change a bit — ``fused=False`` keeps the
 per-tile reference path alive for the equality tests.  ``use_jax=True``
-on a quantized engine selects the jit flavor — int8 gathers +
+(quantized engines only) selects the jit flavor — int8 gathers +
 ``lax.dot_general(..., preferred_element_type=int32)`` + the shared f32
-conversion — which, unlike the exact engine's float32 jit, is *also*
-bitwise (every op is exact-integer or the shared elementwise
-conversion), so it composes with streaming.
+conversion — which is *also* bitwise (every op is exact-integer or the
+shared elementwise conversion), so it composes with streaming.
 
 Bitwise equality holds because every float op is replayed in the
 interpreter's association order: the per-pixel ``(B, C) @ (C, M)`` MACs
@@ -218,14 +217,11 @@ class TraceExecutor:
         # quantized engines ride the fused batch-of-tiles lowering when
         # they expose it; fused=False pins the per-tile reference fold
         self.fused = fused and hasattr(self.engine, "tiles_mac")
-        if use_jax and self.engine.name != "exact" and not self.fused:
+        if use_jax and not self.fused:
             raise ValueError(
-                f"use_jax=True on the {self.engine.name!r} engine is the "
-                "fused integer jit flavor — it has no per-tile form "
-                "(fused=False)")
-        # the engine handle owns the tap/channel-sliced weights; keep the
-        # attribute for the jax path and external inspection
-        self.weights: List[np.ndarray] = self.handle.tile_w
+                "use_jax=True is the quantized engines' fused integer jit "
+                f"flavor; the {self.engine.name!r} engine with "
+                f"fused={fused} has no jit form")
         self._psum_bytes = sched.c_out * PSUM_BYTES
         self._jax_fn = None
         self._deq_positive = (None, False)   # (handle, all deq > 0)
@@ -250,9 +246,7 @@ class TraceExecutor:
             ifm = ifm[None]
         b = ifm.shape[0]
         assert ifm.shape[1:] == (s.h, s.w, s.c_in), ifm.shape
-        if self.use_jax and self.engine.name == "exact":
-            out = self._run_jax(ifm)
-        elif self.fused:
+        if self.fused:
             qs8 = self._stage_quant(ifm)
             out = self._run_jax_quant(qs8) if self.use_jax \
                 else self._execute_quant(qs8)
@@ -385,7 +379,7 @@ class TraceExecutor:
             out[:, lo:hi] = codes.reshape(b, hi - lo, m)
         return self._tail_np(out.reshape(b, s.e, s.f, m))
 
-    # -- quantized jax fast path (bitwise, unlike the exact f32 one) ---------
+    # -- quantized jax fast path (bitwise) ------------------------------------
 
     def _run_jax_quant(self, qs8: np.ndarray) -> np.ndarray:
         """jit flavor of the fused path on the int8 stream ``qs8``: int8
@@ -527,73 +521,6 @@ class TraceExecutor:
         np.maximum(row[:, 0], row[:, 1], out=out)
         for y in range(2, ps):
             np.maximum(out, row[:, y], out=out)
-
-    # -- jax fast path (behind the flag; float32, approximate) ---------------
-
-    def _run_jax(self, ifm: np.ndarray) -> np.ndarray:
-        """``jax.jit``-compiled variant of the same plan.  Computes in
-        float32 (no x64 requirement), so it is *allclose* to — not
-        bitwise-equal with — the numpy path; counters are identical."""
-        if self._jax_fn is None:
-            with span(f"jit_build:{self.sched.layer_name}", cat="jit"):
-                self._jax_fn = self._build_jax_fn()
-        out = self._jax_fn(np.asarray(ifm, np.float32))
-        return np.asarray(out, np.float64)
-
-    def _build_jax_fn(self):
-        import jax
-        import jax.numpy as jnp
-
-        s, plan = self.sched, self.plan
-        ef = plan.fires
-        bias = None if self.bias is None else np.asarray(self.bias, np.float32)
-        # Within one group the (tile, tap) pairs partition a slice of the
-        # K*K*C contraction exactly once each, so each group is ONE
-        # im2col-style gemm (patches concatenated along the contraction
-        # axis, packed-tap weights stacked), and the group fold is the
-        # same segment sum the Rofm adders perform.  Summation order
-        # inside a group differs from the interpreter (this path is
-        # allclose, not bitwise — the numpy path is the bitwise one), but
-        # a few big gemms are what XLA's CPU backend actually runs fast.
-        wcats = [
-            np.concatenate(
-                [self.weights[t][d] for t in range(lo, hi)
-                 for d in range(self.weights[t].shape[0])],
-                axis=0).astype(np.float32)
-            for lo, hi in plan.segments
-        ]
-
-        def fn(ifm, wstacks):
-            b = ifm.shape[0]
-            padded = jnp.zeros((b, s.hp, s.wp, s.c_in), jnp.float32)
-            padded = padded.at[:, s.pad:s.pad + s.h,
-                               s.pad:s.pad + s.w].set(ifm)
-            stream = padded.reshape(b, -1, s.c_in)
-            gsum = None
-            for (lo, hi), wstack in zip(plan.segments, wstacks):
-                cols = []
-                for t in range(lo, hi):
-                    tt = plan.tiles[t]
-                    for d in range(tt.pack):
-                        patch = jnp.take(stream, tt.gather[d], axis=1)
-                        cols.append(patch[:, :, tt.c_lo:tt.c_hi])
-                patches = jnp.concatenate(cols, axis=2)  # (B, EF, K_group)
-                g = (patches.reshape(b * ef, -1) @ wstack
-                     ).reshape(b, ef, s.c_out)
-                gsum = g if gsum is None else g + gsum
-            out = gsum.reshape(b, s.e, s.f, s.c_out)
-            if bias is not None:
-                out = out + bias
-            if s.tail.activation == "relu":
-                out = jnp.maximum(out, 0.0)
-            ps = s.tail.pool_s
-            if ps:
-                win = out.reshape(b, s.e // ps, ps, s.f // ps, ps, s.c_out)
-                out = win.max(axis=(2, 4))
-            return out
-
-        jitted = jax.jit(fn)
-        return lambda ifm: jitted(ifm, wcats)
 
     # -- analytic counters (same events the interpreter tallies per cycle) ---
 

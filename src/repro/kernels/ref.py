@@ -1,8 +1,6 @@
 """Pure-jnp oracles for every Pallas kernel in this package."""
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
@@ -100,28 +98,3 @@ def int8_matmul_exact_ref(xq: jax.Array, wq: jax.Array) -> jax.Array:
         (((1,), (0,)), ((), ())),
     ).astype(jnp.float32)
 
-
-# ---------------------------------------------------------------------------
-# local (sliding-window) flash attention oracle
-# ---------------------------------------------------------------------------
-
-
-def local_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
-                        window: int, causal: bool = True,
-                        softcap: Optional[float] = None) -> jax.Array:
-    """Oracle for the Pallas sliding-window attention kernel.
-
-    q, k, v: (B, H, S, D).  Token i attends to [i-window+1, i] (causal).
-    """
-    b, h, s, d = q.shape
-    scale = d ** -0.5
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
-    if softcap is not None:
-        logits = jnp.tanh(logits / softcap) * softcap
-    qi = jnp.arange(s)[:, None]
-    ki = jnp.arange(s)[None, :]
-    mask = ki <= qi if causal else jnp.ones((s, s), bool)
-    mask = mask & (ki > qi - window)
-    logits = jnp.where(mask, logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)

@@ -1,97 +1,33 @@
-"""Serve-step construction: prefill + decode with sharded KV caches.
+"""Domino streaming front end: closed-loop serving over the pipelined
+simulator.
 
-Cache PartitionSpecs are auto-derived exactly like params (global vs
-per-device shapes of ``init_cache``), covering every cache flavor:
-GQA (sharded / group-trick / replicated heads), MLA compressed latents,
-mamba states, sliding-window ring buffers, int8 quantized caches.
-
-This module also hosts the **Domino streaming front-end**
-(:func:`serve_stream`): a request-queue loop that feeds image frames
-into the pipelined streaming simulator (``core/network.py``) at a
-configurable offered rate and reports closed-loop latency/throughput
-histograms — the serving-side view of the paper's stream computing.
+:func:`build_stream_sim` builds a streaming ``NetworkSimulator``
+(``core/network.py``) for float or int8-quantized CNN parameters, and
+:func:`serve_stream` feeds it image frames from a request queue at a
+configurable offered rate and reports closed-loop latency and
+throughput histograms: the serving-side view of the paper's stream
+computing.
 """
 from __future__ import annotations
 
-import functools
 import itertools
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs.base import ModelConfig, ParallelConfig
 from repro.core.engine import is_quantized_leaf as _is_q_leaf
-from repro.models import encdec as ED
-from repro.models import transformer as T
-from repro.models.common import ShardingPlan, resolve_w
-from repro.runtime.partition import derive_specs, shardings_from_specs
-from repro.runtime.train_loop import _batch_pspec, _shard_map, make_plan
-
-
-#: leaf names that are true matmul weights (safe to int8-quantize with
-#: per-output-column scales).  Name-allowlisted: scan-stacking makes shape
-#: heuristics ambiguous (a stacked bias (count, d) looks like a matrix).
-QUANTIZABLE = frozenset({
-    "wq", "wk", "wv", "wo", "w_in", "w_out", "w_gate",
-    "w_uq", "w_uk", "w_uv", "w_dq", "w_dkv", "head",
-    "shared_in", "shared_out", "shared_gate", "frontend_proj",
-    "w_in_x", "w_in_z", "x_proj", "dt_proj", "proj",
-})
-
-
-def quantize_decisions(params, min_size: int = 1 << 14) -> Dict[str, bool]:
-    """Which leaves get int8 CIM residency — decided on *global* shapes so
-    the rule is independent of the tp shard factor."""
-    import re
-
-    out = {}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
-        name = "/".join(str(p) for p in path)
-        last = re.sub(r"[^\w]", "", str(path[-1]))
-        out[name] = bool(
-            last in QUANTIZABLE and leaf.ndim >= 2
-            and leaf.shape[-1] >= 16 and leaf.shape[-2] >= 16
-            and leaf.size >= min_size)
-    return out
-
-
-def quantize_params_for_serving(params, min_size: int = 1 << 14,
-                                decisions: Optional[Dict[str, bool]] = None):
-    """Quantize selected matmul weights to int8 + per-column scale
-    (Domino: 8-bit weights resident in the arrays).
-
-    Consumers of the ``{"q", "s"}`` leaves: the LM layers dequantize on
-    use through ``models/common.py::resolve_w``; the Domino CNN serving
-    path (:func:`build_stream_sim`) hands them to the quantized
-    ``CIMEngine`` which keeps the int8 weights resident.  The explicit
-    float route is :func:`dequantize_params`."""
-    from repro.core.cim import quantize_symmetric
-
-    if decisions is None:
-        decisions = quantize_decisions(params, min_size)
-
-    def one(path, leaf):
-        name = "/".join(str(p) for p in path)
-        if decisions.get(name, False):
-            q, s = quantize_symmetric(leaf.astype(jnp.float32), 8, axis=-2)
-            return {"q": q, "s": s}
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(one, params)
 
 
 def quantize_cnn_params_for_serving(params: Dict[str, Any]
                                     ) -> Dict[str, Any]:
-    """Domino CNN flavor of :func:`quantize_params_for_serving`: every
-    conv kernel / FC matrix becomes ``{"q": int8, "s": (M,)}`` with the
-    per-output-column scale taken over the *flattened contraction*
-    (K*K*C) — the crossbar-resident layout the ``CIMEngine`` consumes
-    directly (``core/engine.py::quantize_weight``, so re-quantizing
-    float params on the engine yields bit-identical weights)."""
+    """Quantize every conv kernel / FC matrix to ``{"q": int8, "s": (M,)}``,
+    with the per-output-column scale taken over the *flattened
+    contraction* (K*K*C) — the crossbar-resident layout the
+    ``CIMEngine`` consumes directly (``core/engine.py::quantize_weight``,
+    so re-quantizing float params on the engine yields bit-identical
+    weights)."""
     from repro.core.engine import quantize_weight
 
     out = {}
@@ -102,9 +38,9 @@ def quantize_cnn_params_for_serving(params: Dict[str, Any]
 
 
 def dequantize_params(params):
-    """The explicit float route for ``{"q", "s"}`` quantized leaves —
-    works on both the LM pytree and the Domino CNN name->array dict.
-    Non-quantized leaves pass through untouched."""
+    """The explicit float route for ``{"q", "s"}`` quantized leaves of a
+    CNN name->array dict (or any pytree).  Non-quantized leaves pass
+    through untouched."""
     def one(leaf):
         if _is_q_leaf(leaf):
             return np.asarray(leaf["q"], np.float32) * np.asarray(
@@ -112,146 +48,6 @@ def dequantize_params(params):
         return leaf
 
     return jax.tree_util.tree_map(one, params, is_leaf=_is_q_leaf)
-
-
-@dataclass
-class ServeProgram:
-    cfg: ModelConfig
-    plan: ShardingPlan
-    mesh: Any
-    param_specs: Any
-    cache_specs: Any
-    cache_global_sds: Any  # ShapeDtypeStructs of the global cache arrays
-    prefill_fn: Callable   # (params, batch) -> (logits, caches)
-    decode_fn: Callable    # (params, token, caches, pos) -> (logits, caches)
-
-
-def build_serve_program(cfg: ModelConfig, mesh, pcfg: ParallelConfig,
-                        batch: int, s_max: int,
-                        kv_dtype: str = "bfloat16",
-                        cim_weights: bool = False,
-                        quant_min_size: int = 1 << 14) -> ServeProgram:
-    plan = make_plan(cfg, mesh, pcfg)
-    is_ed = cfg.is_encdec
-    init_fn_model = ED.init_params if is_ed else T.init_params
-
-    decisions = None
-    if cim_weights:
-        raw_g = jax.eval_shape(
-            lambda k: init_fn_model(k, cfg, plan.as_global()),
-            jax.random.PRNGKey(0))
-        decisions = quantize_decisions(raw_g, quant_min_size)
-
-    def make(k, p):
-        params = init_fn_model(k, cfg, p)
-        if cim_weights:
-            params = quantize_params_for_serving(params, quant_min_size,
-                                                 decisions)
-        return params
-
-    g_shapes = jax.eval_shape(
-        lambda k: make(k, plan.as_global()), jax.random.PRNGKey(0))
-    l_shapes = jax.eval_shape(
-        lambda k: make(k, plan), jax.random.PRNGKey(0))
-    param_specs = derive_specs(g_shapes, l_shapes, plan.tp, plan.tp_axis)
-
-    # cache specs: model sharding from (global vs local) shapes, batch dim
-    # located structurally by comparing shapes at batch vs 2*batch
-    def cache_shapes(p, b):
-        if is_ed:
-            return jax.eval_shape(lambda: ED.init_cache(
-                cfg, p, b, s_max, t_enc=s_max, kv_dtype=kv_dtype))
-        return jax.eval_shape(lambda: T.init_cache(
-            cfg, p, b, s_max, kv_dtype))
-
-    cg = cache_shapes(plan.as_global(), batch)
-    cl = cache_shapes(plan, batch)
-    c2 = cache_shapes(plan, 2 * batch)
-    cache_specs = derive_specs(cg, cl, plan.tp, plan.tp_axis)
-    from repro.runtime.train_loop import dp_size_of
-    dpn = dp_size_of(mesh, plan)
-    dp = None
-    if plan.dp_axes and batch % dpn == 0:
-        dp = plan.dp_axes if len(plan.dp_axes) != 1 else plan.dp_axes[0]
-
-    def add_batch(spec, a, b2):
-        lst = list(spec)
-        for i, (da, db) in enumerate(zip(a.shape, b2.shape)):
-            if da != db and lst[i] is None and dp is not None:
-                lst[i] = dp
-        return P(*lst)
-
-    cache_specs = jax.tree.map(add_batch, cache_specs, cl, c2)
-
-    def prefill_dev(params, batch_in):
-        if is_ed:
-            return ED.prefill(params, batch_in, cfg, plan,
-                              kv_dtype=kv_dtype, s_max=s_max)
-        extras = {k: v for k, v in batch_in.items() if k != "tokens"}
-        return T.prefill(params, batch_in["tokens"], cfg, plan,
-                         extras=extras or None, kv_dtype=kv_dtype,
-                         s_max=s_max)
-
-    def decode_dev(params, token, caches, pos):
-        if is_ed:
-            return ED.decode_step(params, token, caches, pos, cfg, plan,
-                                  kv_dtype=kv_dtype)
-        return T.decode_step(params, token, caches, pos, cfg, plan,
-                             kv_dtype=kv_dtype)
-
-    return ServeProgram(
-        cfg=cfg, plan=plan, mesh=mesh, param_specs=param_specs,
-        cache_specs=cache_specs, cache_global_sds=cg,
-        prefill_fn=_build_prefill(prefill_dev, mesh, plan, param_specs,
-                                  cache_specs),
-        decode_fn=_build_decode(decode_dev, mesh, plan, param_specs,
-                                cache_specs),
-    )
-
-
-def _dp_entry(plan, n, dpn):
-    """data-axis spec entry for a batch of size n (None if it can't shard)."""
-    if not plan.dp_axes or n % dpn != 0:
-        return None
-    return plan.dp_axes if len(plan.dp_axes) != 1 else plan.dp_axes[0]
-
-
-def _build_prefill(prefill_dev, mesh, plan, param_specs, cache_specs):
-    from repro.runtime.train_loop import dp_size_of
-    dpn = dp_size_of(mesh, plan)
-
-    def fn(params, batch_in):
-        bspecs = _batch_pspec(batch_in, plan, dp_size=dpn)
-        dp = _dp_entry(plan, batch_in["tokens"].shape[0], dpn)
-        sm = _shard_map(
-            prefill_dev, mesh,
-            in_specs=(param_specs, bspecs),
-            out_specs=(P(dp, None), cache_specs),
-        )
-        return sm(params, batch_in)
-
-    return fn
-
-
-def _build_decode(decode_dev, mesh, plan, param_specs, cache_specs):
-    from repro.runtime.train_loop import dp_size_of
-    dpn = dp_size_of(mesh, plan)
-
-    def fn(params, token, caches, pos):
-        dp = _dp_entry(plan, token.shape[0], dpn)
-        sm = _shard_map(
-            decode_dev, mesh,
-            in_specs=(param_specs, P(dp), cache_specs, P()),
-            out_specs=(P(dp, None), cache_specs),
-        )
-        return sm(params, token, caches, pos)
-
-    return fn
-
-
-# ---------------------------------------------------------------------------
-# Domino streaming front-end (closed-loop serving over the pipelined sim)
-# ---------------------------------------------------------------------------
 
 
 @dataclass
@@ -517,16 +313,3 @@ def _export_serve_metrics(metrics, labels: Dict[str, str],
         "serve_straggler_escalate", "monitor escalation tripped",
         lnames)).set(1.0 if report.straggler_escalate else 0.0)
 
-
-def greedy_generate(serve: ServeProgram, params, batch_in, steps: int):
-    """Batched greedy generation loop for the examples."""
-    logits, caches = jax.jit(serve.prefill_fn)(params, batch_in)
-    pos = batch_in["tokens"].shape[1]
-    decode = jax.jit(serve.decode_fn)
-    token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    out = [token]
-    for i in range(steps - 1):
-        logits, caches = decode(params, token, caches, jnp.int32(pos + i))
-        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        out.append(token)
-    return jnp.stack(out, axis=1)

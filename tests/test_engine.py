@@ -295,6 +295,29 @@ def test_network_calibration_covers_every_layer():
         assert cal.a_scale > 0 and cal.gain >= 1.0
 
 
+def test_resnet50_deep_integer_build_is_warning_clean():
+    """{-1, 0, 1} weights grow activations ~1e56 through resnet50-imagenet,
+    past float32's range; inputs scaled by 2^-64 (exact in f32 and f64)
+    keep the engine's float32 calibration forward inside it.  Build and
+    run then raise no RuntimeWarning — an overflowed calibration once
+    surfaced as inf activation scales and invalid-cast warnings at the
+    int8 quantization — and every activation scale is finite."""
+    import warnings
+
+    rng = np.random.default_rng(0)
+    cnn = CNN_BENCHMARKS["resnet50-imagenet"]()
+    params = _int_params(cnn, rng)
+    frames = rng.random((1, cnn.input_hw, cnn.input_hw, 3)) * 2.0 ** -64
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", RuntimeWarning)
+        sim = NetworkSimulator(cnn, params, backend="trace", engine="cim",
+                               calib_images=frames, dup_cap=128)
+        sim.run(frames)
+    scales = [cal.a_scale for cal in sim.pe_engine.calib.values()]
+    assert len(scales) == len(cnn.layers)
+    assert all(np.isfinite(s) and s > 0 for s in scales)
+
+
 # ---------------------------------------------------------------------------
 # Whole-network quantized execution (the acceptance criteria)
 # ---------------------------------------------------------------------------
@@ -381,23 +404,26 @@ def test_network_engine_flag_validation():
     rng = np.random.default_rng(1)
     cnn = CNN_BENCHMARKS["vgg11-cifar10"]()
     params = _int_params(cnn, rng)
-    with pytest.raises(ValueError):  # exact f32 jit is allclose-only
-        NetworkSimulator(cnn, params, backend="trace", trace_jit=True,
-                         streaming=True)
+    for streaming in (False, True):  # the exact engine has no jit flavor
+        with pytest.raises(ValueError, match="quantized engine"):
+            NetworkSimulator(cnn, params, backend="trace", trace_jit=True,
+                             streaming=streaming)
     with pytest.raises(ValueError):  # calib images are a quantized knob
         NetworkSimulator(cnn, params, calib_images=np.zeros((1, 32, 32, 3)))
     with pytest.raises(ValueError):
         NetworkSimulator(cnn, params, engine="bogus")
+    sched, wts, ifm = _block(3)
+    with pytest.raises(ValueError, match="no jit form"):  # exact engine
+        TraceExecutor(sched, wts, use_jax=True)
     with pytest.raises(ValueError):  # quantized jit has no per-tile form
-        sched, wts, ifm = _block(3)
         TraceExecutor(sched, wts, use_jax=True, fused=False,
                       engine=_cal(CIMEngine(LOSSY), sched.layer_name, ifm))
 
 
 def test_network_quantized_trace_jit_is_bitwise():
     """trace_jit on a quantized engine is the fused integer jit flavor —
-    bitwise with the numpy trace (unlike the exact engine's f32 jit),
-    and therefore allowed to combine with streaming."""
+    bitwise with the numpy trace, and therefore allowed to combine with
+    streaming."""
     rng = np.random.default_rng(6)
     cnn = CNN_BENCHMARKS["vgg11-cifar10"]()
     params = {k: v * 0.1 for k, v in _int_params(cnn, rng).items()}
@@ -466,22 +492,3 @@ def test_exact_engine_rejects_quantized_params(vgg11_quantized):
     cnn, params, qparams, frames = vgg11_quantized
     with pytest.raises(ValueError, match="dequantize"):
         NetworkSimulator(cnn, qparams, backend="trace")
-
-
-def test_lm_quantize_roundtrip_still_consumed():
-    """The LM side of the satellite: quantize_params_for_serving leaves
-    are consumed by resolve_w (models/common.py) — dequantize_params is
-    the explicit route and matches resolve_w's arithmetic."""
-    import jax.numpy as jnp
-
-    from repro.models.common import resolve_w
-    from repro.runtime.serve_loop import (dequantize_params,
-                                          quantize_params_for_serving)
-
-    rng = np.random.default_rng(4)
-    params = {"wq": jnp.asarray(rng.standard_normal((32, 64)), jnp.float32)}
-    qp = quantize_params_for_serving(params, min_size=1)
-    assert isinstance(qp["wq"], dict) and "q" in qp["wq"]
-    via_resolve = np.asarray(resolve_w(qp["wq"], like=params["wq"]))
-    via_deq = np.asarray(dequantize_params(qp)["wq"])
-    np.testing.assert_allclose(via_resolve, via_deq, rtol=1e-6, atol=1e-6)

@@ -141,7 +141,8 @@ def test_stream_flag_validation():
     params = _int_params(cnn, rng)
     with pytest.raises(ValueError):  # streaming needs the trace backend
         NetworkSimulator(cnn, params, streaming=True)
-    with pytest.raises(ValueError):  # jit is allclose-only: no bitwise
+    with pytest.raises(ValueError, match="quantized engine"):
+        # the exact engine has no jit flavor, streaming or not
         NetworkSimulator(cnn, params, backend="trace", trace_jit=True,
                          streaming=True)
     sim = NetworkSimulator(cnn, params, backend="trace")

@@ -1,10 +1,12 @@
 """Compile-only checks for a described TPU v5e chip (no chip attached).
 
-The TPU compiler is installed with jax, so the CIM crossbar kernel and
-the jitted quantized trace step are compiled here at the widths the
-resnet50-imagenet run feeds them.  That catches what interpret mode
-cannot: block shapes off the TPU's (8, 128) tiling, operands in the wrong
-memory space, kernels that do not fit fast memory.  Nothing runs, so
+The TPU compiler is installed with jax, so the CIM crossbar kernel is
+compiled here at the widths the resnet50-imagenet run feeds it, and the
+jitted quantized trace step of every conv geometry the benchmark cells
+run is compiled at that cell's frames per call.  That catches what
+interpret mode cannot: block shapes off the TPU's (8, 128) tiling,
+operands in the wrong memory space, kernels that do not fit fast
+memory.  Nothing runs, so
 these tests say nothing about results or times.
 
 The kernel is interpreted whenever ``jax.default_backend()`` is not a
@@ -17,11 +19,11 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.cnn import CNN_BENCHMARKS
+from repro.configs.cnn import CNN_BENCHMARKS, ConvLayer
 from repro.core.cim import DEFAULT_SPEC
 from repro.core.engine import CIMEngine, PallasEngine
 from repro.core.mapping import plan_network
-from repro.core.schedule import compile_conv_block
+from repro.core.network import compile_conv_layer
 from repro.core.trace import TraceExecutor
 from repro.kernels.cim_matmul import cim_matmul_pallas, interpret_mode
 
@@ -35,6 +37,8 @@ WIDTHS = {
     "s2_3x3": (392, 9 * 256, 256),
     "fc_grid_tile": (2, 256, 256),
 }
+
+ENGINES = {"cim": CIMEngine, "pallas": PallasEngine}
 
 
 @pytest.fixture(scope="module")
@@ -101,30 +105,68 @@ def test_cim_kernel_compiles_for_v5e(width, variation, one_chip,
     assert (out.shape, out.dtype) == ((rows, m), jnp.float32)
 
 
-@pytest.mark.parametrize("engine", [CIMEngine, PallasEngine],
-                         ids=["cim", "pallas"])
-def test_quantized_trace_step_compiles_for_v5e(engine, one_chip,
-                                               tpu_backend):
-    """The jitted trace step of resnet50's widest 3x3 layer (s3b0_b:
-    7x7x512 -> 512, 18 tiles), lowered from shapes for a 2-frame
-    stream; on the Pallas engine it holds the compiled kernel."""
-    cnn = CNN_BENCHMARKS["resnet50-imagenet"]()
-    names = [layer.name for layer in cnn.layers]
-    li = names.index("s3b0_b")
-    layer, lp = cnn.layers[li], plan_network(cnn, dup_cap=128).layers[li]
-    sched = compile_conv_block(
-        layer.name, layer.h, layer.w, layer.c, layer.m, layer.k, layer.s,
-        layer.p, pack=lp.pack, c_splits=lp.c_splits)
+def _cell_geometries():
+    """One case per distinct jitted trace step the benchmark cells run:
+    (cell, engine, layer, strip, frames), at the cell's ``dup_cap`` and
+    frames per call, deduplicated by schedule shape.  Width-striped
+    layers contribute each strip's schedule, as ``NetworkSimulator``
+    builds them."""
+    cells = [  # (config, dup_cap, frames per call, engines)
+        ("resnet50-imagenet", 128, 16, ("pallas", "cim")),
+        ("resnet18-cifar10", 64, 4, ("pallas",)),
+        ("vgg16-imagenet", 64, 16, ("pallas",)),
+    ]
+    cases = []
+    for model, dup_cap, frames, engines in cells:
+        cnn = CNN_BENCHMARKS[model]()
+        seen = set()
+        for layer, lp in zip(cnn.layers,
+                             plan_network(cnn, dup_cap=dup_cap).layers):
+            if not isinstance(layer, ConvLayer):
+                continue
+            sched = compile_conv_layer(layer, lp)
+            strips = [st.sched for st in sched] \
+                if isinstance(sched, tuple) else [sched]
+            for si, sc in enumerate(strips):
+                key = (sc.h, sc.w, sc.c_in, sc.c_out, sc.k, sc.stride,
+                       sc.pad, sc.tail.pool_s, sc.pack, sc.c_splits)
+                if key in seen:
+                    continue
+                seen.add(key)
+                strip = si if isinstance(sched, tuple) else None
+                cases += [pytest.param(
+                    model, dup_cap, frames, eng, layer.name, strip,
+                    id=f"{model.split('-')[0]}-{eng}-{layer.name}"
+                       + ("" if strip is None else f"-strip{si}"))
+                    for eng in engines]
+    return cases
+
+
+@pytest.mark.parametrize("model,dup_cap,frames,engine,layer_name,strip",
+                         _cell_geometries())
+def test_quantized_trace_step_compiles_for_v5e(model, dup_cap, frames,
+                                               engine, layer_name, strip,
+                                               one_chip, tpu_backend):
+    """The jitted trace step of one benchmark cell's conv layer (or
+    width strip), lowered from shapes for one call's frames; on the
+    Pallas engine it holds the compiled kernel."""
+    cnn = CNN_BENCHMARKS[model]()
+    li = [layer.name for layer in cnn.layers].index(layer_name)
+    layer = cnn.layers[li]
+    sched = compile_conv_layer(
+        layer, plan_network(cnn, dup_cap=dup_cap).layers[li])
+    if strip is not None:
+        sched = sched[strip].sched
     wts = np.random.default_rng(0).standard_normal(
         (layer.k, layer.k, layer.c, layer.m))
-    eng = engine().set_layer(layer.name, a_scale=0.05)
+    eng = ENGINES[engine]().set_layer(layer.name, a_scale=0.05)
     ex = TraceExecutor(sched, wts, engine=eng, use_jax=True)
     step = ex._build_jax_qfn()
-    stream = jax.ShapeDtypeStruct((2, sched.hp * sched.wp, sched.c_in),
-                                  jnp.int8, sharding=one_chip)
+    stream = jax.ShapeDtypeStruct(
+        (frames, sched.hp * sched.wp, sched.c_in), jnp.int8,
+        sharding=one_chip)
     compiled = step.func.lower(_shapes(step.args[0], one_chip),
                                stream).compile()
     out = compiled.out_info
-    assert out.shape == (2 * sched.e * sched.f, layer.m)
-    assert ("tpu_custom_call" in compiled.as_text()) == (
-        engine is PallasEngine)
+    assert out.shape == (frames * sched.e * sched.f, layer.m)
+    assert ("tpu_custom_call" in compiled.as_text()) == (engine == "pallas")

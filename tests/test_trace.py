@@ -2,8 +2,8 @@
 equal to the per-cycle interpreter — OFM values, ``SimCounters``,
 ``TrafficCounters`` and per-link mesh traffic — for every conv geometry
 appearing in any ``CNN_BENCHMARKS`` mapping plan (incl. pool strides and
-C > N_c channel-split chains), batched and unbatched; the ``jax.jit``
-flavor is allclose (float32); and the whole-network trace backend
+C > N_c channel-split chains), batched and unbatched; and the
+whole-network trace backend
 reproduces the interpreter run and the jax reference exactly, now
 including ResNet-18's residual wiring."""
 import dataclasses
@@ -150,23 +150,6 @@ def test_trace_plan_shapes():
         # every gathered index addresses the padded raster stream
         assert tt.gather.min() >= 0
         assert tt.gather.max() < plan.n_pix
-
-
-def test_trace_jax_flavor_allclose():
-    h = w = 8
-    c, m, k = 4, 5, 3
-    ifm = _int_data(31, (2, h, w, c), lo=0, hi=3)
-    wts = _int_data(32, (k, k, c, m), lo=-1, hi=2)
-    sched = compile_conv_block("jit", h, w, c, m, k, 1, 1,
-                               pool_k=2, pool_s=2, activation="relu")
-    ref = TraceExecutor(sched, wts).run(ifm)
-    jit = TraceExecutor(sched, wts, use_jax=True)
-    out = jit.run(ifm)
-    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
-    # counters are analytic — identical across flavors
-    plain = TraceExecutor(sched, wts)
-    plain.run(ifm)
-    assert jit.counters == plain.counters
 
 
 # ---------------------------------------------------------------------------
